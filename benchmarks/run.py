@@ -73,6 +73,7 @@ from benchmarks import (bench_accuracy, bench_collectives,
                         bench_ecm_predictions, bench_kernel_throughput,
                         bench_quant, bench_scaling, bench_serving,
                         bench_spec, bench_tpu_kahan, roofline_report)
+from repro.launch.compile_cache import enable_compile_cache
 
 MODULES = [
     bench_ecm_predictions,
@@ -260,6 +261,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.json == "auto":
         args.json = default_json_path()
+    enable_compile_cache()
 
     modules = MODULES
     if args.only:

@@ -26,11 +26,15 @@ arXiv:1604.01890 §4.2) on the TPU VPU:
    paper's "reduce partial sums scalar-ly at the end" strategy, so the
    final fold reintroduces no O(streams·eps) error.
 
-Inputs are streamed as flat 1-D blocks; the final partial block is masked
-in-kernel against the static element count (global-iota compare), so the
-host-side canonicalization never materializes a zero-padded copy of the
-operands (Pallas pads the out-of-bounds tail of the last block with
-unspecified values; the mask makes the kernel independent of them).
+Operands are laid out as lane-dense ``(rows, 128)`` tiles and streamed
+in ``(block_rows, 128)`` blocks, ``block_rows`` a multiple of ``8 * U``:
+the layout the TPU's Pallas lowering accepts for any operand length. An
+operand whose length is a multiple of 128 (the hot paths: vocab rows,
+power-of-two sweeps) is re-viewed for free; a ragged one pays a single
+pad to the next lane multiple. The final partial block is masked
+in-kernel against the static element count (global-iota compare), so
+the kernel is independent of the pad values and of the unspecified tail
+Pallas loads past the array end.
 
 Fused multi-reduction: one pass over the operands can emit any subset of
 
@@ -135,10 +139,12 @@ def _engine_kernel(*refs, outputs, n_operands, n_valid, block_elems,
                    unroll, acc_dtype, compensated, batched):
     """Grid-sequential fused reduction body.
 
-    ``refs`` layout: operand refs, then one out ref per output, then
-    scratch refs (a (U,8,128) sum + carry pair per compensated output —
-    or a single (8,128) plain accumulator in naive mode — and one
-    (8,128) running-max buffer per max output).
+    ``refs`` layout: operand refs ((block_rows, 128) tiles, with a
+    leading unit row axis when ``batched``), then one (8, 128) out ref
+    per output (the statistic broadcast over the tile), then scratch
+    refs (a (U,8,128) sum + carry pair per compensated output — or a
+    single (8,128) plain accumulator in naive mode — and one (8,128)
+    running-max buffer per max output).
     """
     operands = refs[:n_operands]
     out_refs = refs[n_operands:n_operands + len(outputs)]
@@ -206,16 +212,12 @@ def _engine_kernel(*refs, outputs, n_operands, n_valid, block_elems,
         # segment of the block. One vectorized Neumaier step updates all
         # U (8,128) accumulators at once; the serial dependency chain per
         # block is `chunks` steps instead of `chunks * U`.
+        # The chunk loop is unrolled at trace time (static indices only:
+        # the TPU lowering has no in-kernel dynamic_slice of a value).
         r = contribs[o].reshape(unroll, chunks, SUBLANES, LANES)
-        if chunks == 1:
-            s, c = kahan.neumaier_step(s_ref[...], c_ref[...], r[:, 0])
-        else:
-            def body(i, sc, r=r):
-                s, c = sc
-                chunk = jax.lax.dynamic_slice_in_dim(r, i, 1, axis=1)
-                return kahan.neumaier_step(s, c, chunk[:, 0])
-            s, c = jax.lax.fori_loop(0, chunks, body,
-                                     (s_ref[...], c_ref[...]))
+        s, c = s_ref[...], c_ref[...]
+        for i in range(chunks):
+            s, c = kahan.neumaier_step(s, c, r[:, i])
         s_ref[...] = s
         c_ref[...] = c
 
@@ -236,7 +238,8 @@ def _engine_kernel(*refs, outputs, n_operands, n_valid, block_elems,
                     val = jnp.sum(s_ref[...])
             else:
                 val = jnp.max(max_accs[o][...])
-            out_ref[...] = val.reshape(1, 1).astype(out_ref.dtype)
+            out_ref[...] = jnp.broadcast_to(val, out_ref.shape).astype(
+                out_ref.dtype)
 
 
 # ----------------------------------------------------------- launchers ----
@@ -253,6 +256,16 @@ def _scratch_shapes(outputs, unroll, acc_dtype, compensated):
         else:
             shapes.append(pltpu.VMEM((SUBLANES, LANES), acc_dtype))
     return shapes
+
+
+def _lane_tiles(x: jax.Array) -> jax.Array:
+    """[..., n] -> [..., ceil(n / 128), 128]: free for n % 128 == 0,
+    otherwise one pad of the last axis to the next lane multiple (the
+    kernel masks the pad)."""
+    pad = -x.shape[-1] % LANES
+    if pad:
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(*x.shape[:-1], x.shape[-1] // LANES, LANES)
 
 
 def fused_reduce_flat(operands, *, outputs, unroll: int | None = None,
@@ -277,6 +290,7 @@ def fused_reduce_flat(operands, *, outputs, unroll: int | None = None,
                    else block_elems)
     assert block_elems % (unroll * TILE) == 0, (block_elems, unroll)
     acc_dtype = jnp.promote_types(operands[0].dtype, jnp.float32)
+    block_rows = block_elems // LANES
     grid = (pl.cdiv(n, block_elems),)
 
     outs = pl.pallas_call(
@@ -285,16 +299,17 @@ def fused_reduce_flat(operands, *, outputs, unroll: int | None = None,
             n_valid=n, block_elems=block_elems, unroll=unroll,
             acc_dtype=acc_dtype, compensated=compensated, batched=False),
         grid=grid,
-        in_specs=[pl.BlockSpec((block_elems,), lambda g: (g,))
+        in_specs=[pl.BlockSpec((block_rows, LANES), lambda g: (g, 0))
                   for _ in operands],
-        out_specs=[pl.BlockSpec((1, 1), lambda g: (0, 0))
+        out_specs=[pl.BlockSpec((SUBLANES, LANES), lambda g: (0, 0))
                    for _ in outputs],
-        out_shape=[jax.ShapeDtypeStruct((1, 1), acc_dtype)
+        out_shape=[jax.ShapeDtypeStruct((SUBLANES, LANES), acc_dtype)
                    for _ in outputs],
         scratch_shapes=_scratch_shapes(outputs, unroll, acc_dtype,
                                        compensated),
         interpret=interpret,
-    )(*operands)
+        name="fused_reduce_flat",
+    )(*(_lane_tiles(op) for op in operands))
     return tuple(o[0, 0] for o in outs)
 
 
@@ -322,6 +337,7 @@ def fused_reduce_rows(operands, *, outputs, unroll: int | None = None,
                    else block_elems)
     assert block_elems % (unroll * TILE) == 0, (block_elems, unroll)
     acc_dtype = jnp.promote_types(operands[0].dtype, jnp.float32)
+    block_rows = block_elems // LANES
     grid = (b, pl.cdiv(n, block_elems))
 
     outs = pl.pallas_call(
@@ -330,14 +346,17 @@ def fused_reduce_rows(operands, *, outputs, unroll: int | None = None,
             n_valid=n, block_elems=block_elems, unroll=unroll,
             acc_dtype=acc_dtype, compensated=compensated, batched=True),
         grid=grid,
-        in_specs=[pl.BlockSpec((1, block_elems), lambda i, g: (i, g))
+        in_specs=[pl.BlockSpec((1, block_rows, LANES),
+                               lambda i, g: (i, g, 0))
                   for _ in operands],
-        out_specs=[pl.BlockSpec((1, 1), lambda i, g: (i, 0))
+        out_specs=[pl.BlockSpec((1, SUBLANES, LANES),
+                                lambda i, g: (i, 0, 0))
                    for _ in outputs],
-        out_shape=[jax.ShapeDtypeStruct((b, 1), acc_dtype)
+        out_shape=[jax.ShapeDtypeStruct((b, SUBLANES, LANES), acc_dtype)
                    for _ in outputs],
         scratch_shapes=_scratch_shapes(outputs, unroll, acc_dtype,
                                        compensated),
         interpret=interpret,
-    )(*operands)
-    return tuple(o[:, 0] for o in outs)
+        name="fused_reduce_rows",
+    )(*(_lane_tiles(op) for op in operands))
+    return tuple(o[:, 0, 0] for o in outs)

@@ -1,9 +1,9 @@
 """Public jit'd wrappers for the reduction-engine kernels.
 
-Handles canonicalization (flatten only — the engine masks the final
-partial block in-kernel, so NO zero-padded copy of the input is ever
-materialized), interpret-mode selection (auto-on for CPU, i.e. this
-container; off on real TPU), dtype policy, and the unroll default.
+Handles canonicalization (flatten only — the engine lays the operand out
+as (rows, 128) lane tiles and masks the final partial block in-kernel),
+interpret-mode selection (on off-TPU; off on a TPU, where every kernel
+compiles), dtype policy, and the unroll default.
 
 Single-output reductions (``kahan_dot``, ``kahan_sum``, ``naive_dot``)
 and the fused multi-reductions (``fused_reduce``, ``batched_fused_reduce``,

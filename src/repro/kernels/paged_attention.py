@@ -26,7 +26,7 @@ repeated at the attention layer, parameterized by
     SAME block table. fp8 payloads widen by bit reinterpretation
     (``quant.core.cast_f32``), never XLA's slow elementwise convert.
   * **dequant mode — the fp8-regression fix**: scales are loaded once
-    per (block, head) and folded *post-dot* into the unrolled streams:
+    per block and folded *post-dot* into the unrolled streams:
     ``s = (q · K_raw) · attn_scale · kscale[None, :]`` on the K side and
     ``p' = p · vscale[None, :]`` before the p·V fold. The multiplies
     land on the [rows, bs] score tile instead of the [bs, head_dim]
@@ -41,14 +41,19 @@ repeated at the attention layer, parameterized by
     once for both uses; the kernel emits context latents and the caller
     applies the absorbed ``wv_b``.
 
-The walk itself is unchanged from the original decode kernel: grid
-(batch, kv-head, table slot) with scalar prefetch — the block-table
-index feeds the BlockSpec index map, so each grid step DMAs exactly one
-pool block from HBM — and the online-softmax normalizer and output
-accumulator keep compensated (sum, carry) stream pairs
-(``kahan.neumaier_step``, rescale applied to sum AND carry — the
-DESIGN.md §4.2 decay-scaling rule). Blocks entirely past a sequence's
-length skip their MXU work via ``pl.when``.
+The walk: grid (batch, table slot) with scalar prefetch — the
+block-table index feeds the BlockSpec index map, so each grid step DMAs
+exactly one pool block, for all kv heads at once, from HBM. Pools are
+viewed lane-dense as [nb, bs, hkv * d] (a free reshape of the
+[nb, bs, hkv, d] layout every cache module keys on), so each block's
+trailing two dims are the array's own: the TPU lowering refuses a block
+whose second-minor dim is 1 of hkv, which a per-head grid axis needs.
+The kv heads are a static loop inside the step, each reading its lane
+window. The online-softmax normalizer and output accumulator keep
+compensated (sum, carry) stream pairs per head (``kahan.neumaier_step``,
+rescale applied to sum AND carry — the DESIGN.md §4.2 decay-scaling
+rule). Blocks entirely past a sequence's length skip their MXU work via
+``pl.when``.
 
 Exposed through the single ``ops.paged_attention`` dispatch
 (auto-interpret on CPU) and validated by the bitwise parity grid in
@@ -105,12 +110,13 @@ def fold_softmax_block(s, v, vs, j, *, bs: int, rows: int, row_limits,
 
     s: [rows, bs] scores with the attention scale and any K-side dequant
     scales already folded in; v: [bs, dv] f32 value payload (raw-cast for
-    quantized pools); vs: [bs] V-side dequant scales folded into the
+    quantized pools); vs: [1, bs] V-side dequant scales folded into the
     post-softmax probabilities (None for bf16) — the normalizer sums the
     UNSCALED p, so out = Σ p·(vs·v) / Σ p is exactly softmax over
     dequantized values; row_limits: [rows, 1] exclusive per-row key
     limits (the query-width masking). The softmax rescale multiplies sum
-    AND carry (decay-scaling rule).
+    AND carry (decay-scaling rule). The scratch refs are one kv head's
+    [rows, 128] / [rows, dv] views.
     """
     k_pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
     mask = k_pos < row_limits
@@ -123,7 +129,7 @@ def fold_softmax_block(s, v, vs, j, *, bs: int, rows: int, row_limits,
                                  lc_scr[...][:, :1] * corr,
                                  p.sum(axis=-1, keepdims=True))
     pv = jax.lax.dot_general(
-        p if vs is None else p * vs[None, :], v,
+        p if vs is None else p * vs, v,
         (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # [rows, dv]
     accs, accc = kahan.neumaier_step(accs_scr[...] * corr,
@@ -136,45 +142,47 @@ def fold_softmax_block(s, v, vs, j, *, bs: int, rows: int, row_limits,
 
 
 def emit_softmax_output(o_ref, ls_scr, lc_scr, accs_scr, accc_scr) -> None:
-    """Normalize the compensated accumulators into the output block."""
-    l = ls_scr[...][:, :1] + lc_scr[...][:, :1]
+    """Normalize every head's compensated accumulators into the output
+    block [1, hkv, rows, dv]."""
+    l = ls_scr[...][..., :1] + lc_scr[...][..., :1]
     acc = accs_scr[...] + accc_scr[...]
-    o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
-def paged_grid_spec(b: int, hkv: int, mb: int, bs: int, rows: int,
-                    q_dims: tuple, kv_dims: tuple, dv: int,
+def paged_grid_spec(b: int, mb: int, bs: int, hkv: int, rows: int,
+                    q_dims: tuple, kv_widths: tuple, dv: int,
                     n_scales: int) -> "pltpu.PrefetchScalarGridSpec":
-    """Grid over (batch, kv-head, table slot) with the (block_table, lens,
-    q_offsets) scalar prefetch. ``q_dims``/``kv_dims`` give the trailing
-    dim of each query operand ([b, hkv, rows, d]) and each pool operand
-    ([nb, bs, hkv, d]); ``n_scales`` appends that many [nb, bs, hkv]
-    scale-tile operands following the same table-indexed walk — ONE
-    scale DMA per (block, head), not per element."""
+    """Grid over (batch, table slot) with the (block_table, lens,
+    q_offsets) scalar prefetch; each step DMAs one pool block for ALL kv
+    heads. ``q_dims`` gives the trailing dim of each query operand
+    ([b, hkv, rows, d]); ``kv_widths`` the trailing dim of each pool
+    operand, viewed lane-dense as [nb, bs, hkv * d]; ``n_scales`` appends
+    that many [nb, bs, hkv] scale-tile operands following the same
+    table-indexed walk — ONE scale DMA per block, not per element. Every
+    block's trailing two dims are the array's own, the layout the TPU
+    lowering requires of a block that is not (8, 128)-aligned."""
     def q_spec(d):
-        return pl.BlockSpec((1, 1, rows, d), lambda i, h, j, *_: (i, h, 0, 0))
+        return pl.BlockSpec((1, hkv, rows, d), lambda i, j, *_: (i, 0, 0, 0))
 
-    def kv_spec(d):
+    def table_spec(width):
         return pl.BlockSpec(
-            (1, bs, 1, d),
-            lambda i, h, j, table, lens, offs: (table[i, j], 0, h, 0))
+            (1, bs, width),
+            lambda i, j, table, lens, offs: (table[i, j], 0, 0))
 
-    scale_spec = pl.BlockSpec(
-        (1, bs, 1), lambda i, h, j, table, lens, offs: (table[i, j], 0, h))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,               # (block_table, lens, q_offsets)
-        grid=(b, hkv, mb),
+        grid=(b, mb),
         in_specs=[*(q_spec(d) for d in q_dims),
-                  *(kv_spec(d) for d in kv_dims),
-                  *([scale_spec] * n_scales)],
-        out_specs=pl.BlockSpec((1, 1, rows, dv),
-                               lambda i, h, j, *_: (i, h, 0, 0)),
+                  *(table_spec(w) for w in kv_widths),
+                  *([table_spec(hkv)] * n_scales)],
+        out_specs=pl.BlockSpec((1, hkv, rows, dv),
+                               lambda i, j, *_: (i, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((rows, 128), jnp.float32),   # m (col 0 used)
-            pltpu.VMEM((rows, 128), jnp.float32),   # l sum
-            pltpu.VMEM((rows, 128), jnp.float32),   # l carry
-            pltpu.VMEM((rows, dv), jnp.float32),    # acc sum
-            pltpu.VMEM((rows, dv), jnp.float32),    # acc carry
+            pltpu.VMEM((hkv, rows, 128), jnp.float32),   # m (col 0 used)
+            pltpu.VMEM((hkv, rows, 128), jnp.float32),   # l sum
+            pltpu.VMEM((hkv, rows, 128), jnp.float32),   # l carry
+            pltpu.VMEM((hkv, rows, dv), jnp.float32),    # acc sum
+            pltpu.VMEM((hkv, rows, dv), jnp.float32),    # acc carry
         ],
     )
 
@@ -183,18 +191,19 @@ def paged_grid_spec(b: int, hkv: int, mb: int, bs: int, rows: int,
 
 def _super_kernel(table_ref, lens_ref, offs_ref, *refs, mla: bool,
                   quant: bool, scale: float, bs: int, rows: int,
-                  groups: int):
+                  groups: int, hkv: int, dk: int, dv: int):
     """One body for the whole family; ``mla``/``quant`` are trace-time
     flags, so each configuration lowers to a specialized kernel with no
-    in-kernel branching."""
+    in-kernel branching. The kv-head loop is unrolled at trace time: head
+    h reads its static lane window of the lane-dense block."""
     scratch = refs[-5:]
     o_ref = refs[-6]
     ins = refs[:-6]
     m_scr, ls_scr, lc_scr, accs_scr, accc_scr = scratch
 
     b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    j = pl.program_id(1)
+    nj = pl.num_programs(1)
 
     @pl.when(j == 0)
     def _init():
@@ -212,51 +221,52 @@ def _super_kernel(table_ref, lens_ref, offs_ref, *refs, mla: bool,
     # (also exact identity updates — the width-invariance contract).
     @pl.when(j * bs < length)
     def _block():
-        fold = functools.partial(
-            fold_softmax_block, j=j, bs=bs, rows=rows,
-            row_limits=row_limits, m_scr=m_scr, ls_scr=ls_scr,
-            lc_scr=lc_scr, accs_scr=accs_scr, accc_scr=accc_scr)
-        if mla:
-            # two score streams (c_kv latents + shared rope key), value
-            # IS the c_kv block — streamed once, used twice
-            if quant:
-                ql_ref, qr_ref, ck_ref, kr_ref, cs_ref, rs_ref = ins
+        n_q = 2 if mla else 1
+        q_refs, kv_refs, sc_refs = ins[:n_q], ins[n_q:n_q + 2], ins[n_q + 2:]
+        # per-(token row, head) dequant scales [bs, hkv], transposed once
+        # per block so head h's scales are the row [1, bs] of the fold
+        sc = [r[0].T for r in sc_refs]
+        for h in range(hkv):
+            fold = functools.partial(
+                fold_softmax_block, j=j, bs=bs, rows=rows,
+                row_limits=row_limits, m_scr=m_scr.at[h],
+                ls_scr=ls_scr.at[h], lc_scr=lc_scr.at[h],
+                accs_scr=accs_scr.at[h], accc_scr=accc_scr.at[h])
+            hs = [x[h:h + 1] for x in sc]                # [1, bs] each
+            if mla:
+                # one latent "head" (hkv == 1): two score streams (c_kv
+                # latents + shared rope key), value IS the c_kv block —
+                # streamed once, used twice
+                ql_ref, qr_ref = q_refs
+                ck = cast_f32(kv_refs[0][0])               # [bs, c]
+                kr = cast_f32(kv_refs[1][0])               # [bs, r]
+                s_lat = jax.lax.dot_general(
+                    ql_ref[0, 0].astype(jnp.float32), ck,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)    # [rows, bs]
+                s_rope = jax.lax.dot_general(
+                    qr_ref[0, 0].astype(jnp.float32), kr,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                if quant:
+                    cs, rs = hs
+                    fold((s_lat * cs + s_rope * rs) * scale, ck, cs)
+                else:
+                    fold((s_lat + s_rope) * scale, ck, None)
             else:
-                ql_ref, qr_ref, ck_ref, kr_ref = ins
-            ck = cast_f32(ck_ref[0, :, 0, :])              # [bs, c]
-            kr = cast_f32(kr_ref[0, :, 0, :])              # [bs, r]
-            s_lat = jax.lax.dot_general(
-                ql_ref[0, 0].astype(jnp.float32), ck,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)        # [rows, bs]
-            s_rope = jax.lax.dot_general(
-                qr_ref[0, 0].astype(jnp.float32), kr,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            if quant:
-                cs = cs_ref[0, :, 0]                       # [bs]
-                rs = rs_ref[0, :, 0]
-                s = (s_lat * cs[None, :] + s_rope * rs[None, :]) * scale
-                fold(s, ck, cs)
-            else:
-                s = (s_lat + s_rope) * scale
-                fold(s, ck, None)
-        else:
-            if quant:
-                q_ref, k_ref, v_ref, ks_ref, vs_ref = ins
-            else:
-                q_ref, k_ref, v_ref = ins
-            k = cast_f32(k_ref[0, :, 0, :])                # [bs, dk]
-            v = cast_f32(v_ref[0, :, 0, :])                # [bs, dv]
-            s = jax.lax.dot_general(
-                q_ref[0, 0].astype(jnp.float32), k,
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            if quant:
-                # post-dot scale fold: [rows, bs] multiplies, not [bs, dk]
-                fold(s * ks_ref[0, :, 0][None, :], v, vs_ref[0, :, 0])
-            else:
-                fold(s, v, None)
+                k_ref, v_ref = kv_refs
+                k = cast_f32(k_ref[0, :, h * dk:(h + 1) * dk])   # [bs, dk]
+                v = cast_f32(v_ref[0, :, h * dv:(h + 1) * dv])   # [bs, dv]
+                s = jax.lax.dot_general(
+                    q_refs[0][0, h].astype(jnp.float32), k,
+                    (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if quant:
+                    # post-dot scale fold: [rows, bs] multiplies, not [bs, dk]
+                    ks, vs = hs
+                    fold(s * ks, v, vs)
+                else:
+                    fold(s, v, None)
 
     @pl.when(j == nj - 1)
     def _emit():
@@ -282,7 +292,7 @@ def paged_attention_pallas(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     decode q_offsets == lens - 1). Returns [B, W, Hq, Dv] in q's dtype.
     """
     b, w, hq, d = q.shape
-    _, bs, hkv, dk = kpool.shape
+    nb, bs, hkv, dk = kpool.shape
     dv = vpool.shape[-1]
     mb = block_table.shape[1]
     groups = hq // hkv
@@ -295,13 +305,15 @@ def paged_attention_pallas(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
     qg, rows_pad = _pad_rows(qg, rows)
     quant = kscale is not None
 
-    grid_spec = paged_grid_spec(b, hkv, mb, bs, rows_pad, (d,), (dk, dv), dv,
-                                2 if quant else 0)
+    grid_spec = paged_grid_spec(b, mb, bs, hkv, rows_pad, (d,),
+                                (hkv * dk, hkv * dv), dv, 2 if quant else 0)
     kernel = functools.partial(
         _super_kernel, mla=False, quant=quant,
         scale=d ** -0.5 if scale is None else scale, bs=bs, rows=rows_pad,
-        groups=groups)
-    args = [block_table, lens, q_offsets, qg, kpool, vpool]
+        groups=groups, hkv=hkv, dk=dk, dv=dv)
+    # lane-dense pool views: a free reshape of the [nb, bs, Hkv, D] pools
+    args = [block_table, lens, q_offsets, qg,
+            kpool.reshape(nb, bs, hkv * dk), vpool.reshape(nb, bs, hkv * dv)]
     if quant:
         args += [kscale, vscale]
     out = pl.pallas_call(
@@ -309,6 +321,7 @@ def paged_attention_pallas(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, rows_pad, dv), q.dtype),
         interpret=interpret,
+        name="paged_attention",
     )(*args)
     return (out[:, :, :rows]
             .reshape(b, hkv, w, groups, dv).transpose(0, 2, 1, 3, 4)
@@ -340,15 +353,14 @@ def paged_latent_attention_pallas(q_lat: jax.Array, q_rope: jax.Array,
     rows = w * h
     ql, rows_pad = _pad_rows(q_lat.reshape(b, 1, rows, c), rows)
     qr, _ = _pad_rows(q_rope.reshape(b, 1, rows, r), rows)
-    ck = ck_pool[:, :, None, :]                  # [nb, bs, 1, c]
-    kr = kr_pool[:, :, None, :]
     quant = ck_scale is not None
 
-    grid_spec = paged_grid_spec(b, 1, mb, bs, rows_pad, (c, r), (c, r), c,
+    grid_spec = paged_grid_spec(b, mb, bs, 1, rows_pad, (c, r), (c, r), c,
                                 2 if quant else 0)
     kernel = functools.partial(_super_kernel, mla=True, quant=quant,
-                               scale=scale, bs=bs, rows=rows_pad, groups=h)
-    args = [block_table, lens, q_offsets, ql, qr, ck, kr]
+                               scale=scale, bs=bs, rows=rows_pad, groups=h,
+                               hkv=1, dk=c, dv=c)
+    args = [block_table, lens, q_offsets, ql, qr, ck_pool, kr_pool]
     if quant:
         args += [ck_scale[:, :, None], kr_scale[:, :, None]]
     out = pl.pallas_call(
@@ -356,5 +368,6 @@ def paged_latent_attention_pallas(q_lat: jax.Array, q_rope: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, 1, rows_pad, c), jnp.float32),
         interpret=interpret,
+        name="paged_attention",
     )(*args)
     return out[:, :, :rows].reshape(b, w, h, c)

@@ -6,6 +6,14 @@ from __future__ import annotations
 import jax
 
 
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with every axis ``Auto``: the mesh GSPMD
+    partitions, and the only one ``with_sharding_constraint`` accepts
+    (``make_mesh`` defaults to ``Explicit`` axes)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """The assignment's production mesh: 16×16 = 256 chips per pod
     ("data", "model"), or 2 pods = 512 chips ("pod", "data", "model").
@@ -22,7 +30,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = math.prod(shape)
     devices = jax.devices()
     if len(devices) == n:
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     if len(devices) > n:
         return jax.sharding.Mesh(
             np.asarray(devices[:n]).reshape(shape), axes)
@@ -37,9 +45,9 @@ def make_test_mesh(*, multi_pod: bool = False):
     for integration tests run under xla_force_host_platform_device_count=8."""
     shape = (2, 2, 2) if multi_pod else (4, 2)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh():
     """Single-device mesh (smoke tests / examples on this CPU container)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

@@ -1,6 +1,8 @@
 """Serving launcher: ``python -m repro.launch.serve --arch <id>`` —
-random-weight continuous-batching demo of the paged-KV decode engine (see
-examples/serve.py for the scripted walkthrough). ``--spec-mode`` switches
+random-weight continuous-batching demo of the paged-KV decode engine at
+the config's published widths (``--reduced`` swaps in the tiny
+same-family config for a quick CPU run; see examples/serve.py for the
+scripted walkthrough). ``--spec-mode`` switches
 on speculative decoding (n-gram prompt-lookup or a draft model from the
 registry); ``--session-kv`` serves multi-turn conversations against the
 session prefix tier (whole-history trie hits, evicted prefixes spilled
@@ -23,6 +25,7 @@ import numpy as np
 
 from repro import obs
 from repro.configs import REGISTRY, get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api, common
 from repro.serving.engine import DecodeEngine, Request, SpecDecodeEngine
 from repro.serving.faults import (FailoverServer, FaultInjector, FaultSpec,
@@ -35,6 +38,11 @@ def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b",
                     choices=sorted(REGISTRY))
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family config (2 layers, "
+                         "d_model 64, vocab 256) instead of the published "
+                         "widths — the CPU demo; applies to --draft-arch "
+                         "too")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--max-new", type=int, default=16)
@@ -219,8 +227,10 @@ def _summary_line(args, snap: dict, n_done: int, total: int,
     """Render the final summary from a metrics snapshot — every number
     here is a snapshot entry, so the line, the ``--metrics`` export and
     the bench counters can never disagree."""
+    dev = jax.devices()[0]
     line = (f"{n_done} requests, {total} tokens in {dt:.1f}s "
-            f"({total/dt:.1f} tok/s, {args.slots} slots, CPU)")
+            f"({total/dt:.1f} tok/s, {args.slots} slots, "
+            f"{dev.platform} {dev.device_kind})")
     if snap["paged_bytes"]:
         ratio = snap["contiguous_bytes"] / snap["paged_bytes"]
         line += (f" | KV touched {snap['paged_bytes']/2**20:.1f} MiB paged "
@@ -261,8 +271,10 @@ def _summary_line(args, snap: dict, n_done: int, total: int,
 
 def main() -> None:
     args = build_parser().parse_args()
+    enable_compile_cache()
+    widths = reduced if args.reduced else (lambda c: c)
 
-    cfg = reduced(get_config(args.arch))
+    cfg = widths(get_config(args.arch))
     if cfg.family not in ("dense", "moe", "ssm", "vlm"):
         raise SystemExit(f"engine serves LM families; {cfg.family} uses the "
                          f"prefill/decode API directly (see repro.models.api)")
@@ -322,7 +334,7 @@ def main() -> None:
         if args.spec_mode == "ngram":
             proposer = NGramProposer()
         else:
-            draft_cfg = reduced(get_config(args.draft_arch)).with_(
+            draft_cfg = widths(get_config(args.draft_arch)).with_(
                 kv_dtype=args.kv_dtype,
                 vocab_size=cfg.vocab_size, tie_embeddings=cfg.tie_embeddings)
             draft_params = common.init_params(api.schema(draft_cfg),

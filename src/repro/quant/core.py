@@ -89,24 +89,29 @@ def e4m3_to_f32(q: Array) -> Array:
     scalar-ish expansion, and when that convert sits fused inside a jitted
     decode step it costs MORE than the fp8 byte cut saves — the root cause
     of the fp8 0.70x decode regression. The same value is reachable with
-    three integer ops: e4m3 (1-4-3) is a bit-subset of f16 (1-5-10), so
-    shifting sign to bit 15 and exponent+mantissa to bits 14..7 yields an
-    f16 whose exponent is biased 15 instead of 7 — multiply by 2^8 after
-    the (hardware-fast) f16->f32 widen and the result is BITWISE the
-    native convert for every e4m3 value, denormals included (verified
-    exhaustively over all 256 bytes in tests/test_quant.py). The two NaN
-    encodings (0x7f/0xff) map to ±480 instead of NaN; quantized caches
-    never store NaN (``_encode`` scales into range), so the hot paths
-    below use this unconditionally.
+    32-bit integer ops only, which is also all the TPU's Pallas lowering
+    offers inside the superkernel (it has no 16-bit shifts): a normal
+    e4m3 magnitude (exponent field >= 1) moves its exponent+mantissa bits
+    to f32 bits 30..20 and re-biases the exponent by 127 - 7 = 120; a
+    denormal (exponent field 0) is its 3-bit mantissa times 2^-9, exact
+    in f32 without producing an f32 denormal (which the TPU would flush).
+    The result is BITWISE the native convert for every e4m3 value
+    (verified exhaustively over all 256 bytes in tests/test_quant.py).
+    The two NaN encodings (0x7f/0xff) map to ±480 instead of NaN;
+    quantized caches never store NaN (``_encode`` scales into range), so
+    the hot paths below use this unconditionally.
 
     Accepts either ``float8_e4m3fn`` values or their raw-byte ``uint8``
     view (how pools store them — see ``QuantFormat.storage``).
     """
-    u8 = (q if q.dtype == jnp.uint8
-          else jax.lax.bitcast_convert_type(q, jnp.uint8)).astype(jnp.uint16)
-    u16 = ((u8 & 0x80) << 8) | ((u8 & 0x7F) << 7)
-    return (jax.lax.bitcast_convert_type(u16, jnp.float16)
-            .astype(jnp.float32) * jnp.float32(256.0))
+    u = (q if q.dtype == jnp.uint8
+         else jax.lax.bitcast_convert_type(q, jnp.uint8)).astype(jnp.int32)
+    mag = u & 0x7F
+    normal = jax.lax.bitcast_convert_type((mag << 20) + (120 << 23),
+                                          jnp.float32)
+    val = jnp.where(mag < 8, mag.astype(jnp.float32) * jnp.float32(2.0 ** -9),
+                    normal)
+    return jnp.where(u >= 0x80, -val, val)
 
 
 def cast_f32(x: Array) -> Array:

@@ -1,7 +1,7 @@
 """Optional-``hypothesis`` shim for the property tests.
 
 Prefers the real ``hypothesis`` package when installed. When it is absent
-(the pinned CI/container image does not ship it), falls back to a tiny
+(it is an optional test dependency), falls back to a tiny
 deterministic property-test driver implementing the subset of the API
 these tests use — ``@given`` over ``integers`` / ``booleans`` /
 ``sampled_from`` / ``lists`` strategies with ``@settings(max_examples=,

@@ -147,7 +147,7 @@ def test_paged_attention_superkernel_blocks_linear():
         assert d2 == pytest.approx(2 * d1, rel=0.10), \
             f"{field}: block increments not linear ({d1:g}, {d2:g})"
     # per-block dot work floor: the score matmul alone is
-    # 2 * rows * bs * d flops per (batch, kv-head) grid step
+    # 2 * rows * bs * d flops per (batch, kv-head) pair of a grid step
     rows = 32                                   # _ROW_TILE padding
     per_block_floor = b * hkv * 2 * rows * bs * d
     assert (costs[4].dot_flops - costs[2].dot_flops) >= 2 * per_block_floor
