@@ -23,8 +23,24 @@ lives. This package is that method applied to the serving engine:
                drift-calibrated machine model, so wall time is
                *attributed* (compute/HBM/host/dispatch/unattributed),
                not just measured
+  span       — a host phase on the PROFILER'S clock:
+               ``jax.profiler.TraceAnnotation``, written into a profiler
+               trace beside the device events, so a device-idle gap can
+               be put down to what the host was doing in it. Always on:
+               under a microsecond a span with no profiler session.
+               ``DecodeEngine.step`` tiles each step with the phases
+               ``engine.admit``, ``engine.prefill_chunk``,
+               ``engine.first_token``, ``engine.decode_step`` (or
+               ``engine.verify_step``, with ``engine.propose`` inside)
+               and ``engine.retire``, and puts every host read that
+               waits on the device in an ``engine.fetch`` of its own.
+               The ``Tracer`` is the deterministic lifecycle log on the
+               engine-step clock; ``span`` is the host phase on the
+               device trace's clock. The phases reuse the Tracer's
+               instant names (``prefill_chunk``, ``decode_step``,
+               ``verify_step``).
 
-``Telemetry`` bundles the three behind one handle; ``NULL`` is the
+``Telemetry`` bundles the first three behind one handle; ``NULL`` is the
 always-off default the engine holds when no telemetry is attached —
 every hot-path hook guards on ``obs.enabled``, so a disabled engine
 runs the exact PR-7 hot path (one fused launch + one transfer per
@@ -44,9 +60,8 @@ from repro.obs.trace import TraceEvent, Tracer
 class Telemetry:
     """One recorder handle: a Tracer + MetricsRegistry + ResidualLog
     sharing the engine-step clock. ``wall_clock=True`` additionally
-    stamps trace events with ``time.perf_counter()`` and lets the
-    engine record wall-denominated histograms; it never changes the
-    deterministic event sequence. ``profile=True`` attaches the ECM
+    stamps trace events with ``time.perf_counter()``; it never changes
+    the deterministic event sequence. ``profile=True`` attaches the ECM
     attribution ``Profiler`` (``self.profile``, else None) — the engine
     then records per-phase HLO cost counters and wall seconds; the
     counter side of the attribution stays deterministic, and the
@@ -56,7 +71,6 @@ class Telemetry:
     enabled = True
 
     def __init__(self, wall_clock: bool = False, profile: bool = False):
-        self.wall_clock = wall_clock
         self.trace = Tracer(wall_clock)
         self.metrics = MetricsRegistry()
         self.residuals = ResidualLog()
@@ -85,7 +99,6 @@ class _NullTelemetry:
     the hot path stays a single predictable attribute check."""
 
     enabled = False
-    wall_clock = False
     profile = None
 
     def set_step(self, step: int) -> None:
@@ -97,9 +110,21 @@ class _NullTelemetry:
 
 NULL = _NullTelemetry()
 
+
+def __getattr__(name: str):
+    # ``span`` is jax.profiler.TraceAnnotation, bound on first use so that
+    # importing this package does not import jax; later lookups find the
+    # class itself, at the annotation's own cost
+    if name == "span":
+        from jax.profiler import TraceAnnotation
+        globals()["span"] = TraceAnnotation
+        return TraceAnnotation
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
+
 __all__ = ["Telemetry", "NULL", "Tracer", "TraceEvent", "MetricsRegistry",
            "Metric", "Counter", "Gauge", "Histogram", "ResidualLog",
-           "ResidualRecord", "residual_row"]
+           "ResidualRecord", "residual_row", "span"]
 # repro.obs.profile (Profiler, Calibration, calibrate) is imported
 # lazily — it pulls in jax/kernels, which plain telemetry users
-# (metrics scraping, trace readers) should not pay for.
+# (metrics scraping, trace readers) should not pay for; so is ``span``.

@@ -127,6 +127,12 @@ class Request:
     error: str | None = None
     submit_step: int = 0
     last_progress_step: int = 0
+    # the same three moments on the host clock (``time.perf_counter``
+    # seconds), once per run: submission, admission into a slot, and the
+    # dispatch of the first prefill chunk. A restore keeps them.
+    submit_time: float | None = None
+    admit_time: float | None = None
+    first_chunk_time: float | None = None
     admit_seq: int = -1
     retries: int = 0
 
@@ -147,6 +153,7 @@ class Request:
         self.prefix_hit = 0
         self.cow_src = None
         self.state = "queued"
+        self.submit_time = self.admit_time = self.first_chunk_time = None
         self.admit_seq = -1
         self.retries += 1
 
@@ -360,6 +367,7 @@ class Scheduler:
             self.waiting.popleft()
             req.slot = self._free_slots.pop()
             req.state = "prefilling"
+            req.admit_time = time.perf_counter()
             req.admit_seq = self._admit_seq
             self._admit_seq += 1
             self.prefilling.append(req)
@@ -646,12 +654,6 @@ class DecodeEngine:
             self._h_queue_wait = m.histogram(
                 "queue_wait_steps", unit="steps",
                 help="engine steps from submit to slot admission")
-            self._h_intertoken = m.histogram(
-                "intertoken_seconds", unit="s",
-                help="wall-clock decode/verify step latency "
-                     "(~ inter-token latency per resident request)",
-                buckets=(1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2,
-                         0.1, 0.3, 1.0, 3.0))
 
         self._prefill_chunk = jax.jit(api.prefill_chunk_fn(cfg))
         decode_raw = api.decode_fn(cfg)
@@ -756,19 +758,45 @@ class DecodeEngine:
         overflow, pool oversubmit, bad deadline)."""
         req.submit_step = self._step_count
         req.last_progress_step = self._step_count
+        req.submit_time = time.perf_counter()
         self.scheduler.submit(req)
         if self.obs.enabled:
             self.obs.trace.begin("queued", rid=req.rid,
                                  prompt_tokens=len(req.prompt),
                                  max_new=req.max_new_tokens)
 
+    # the host span around the batched decode (``repro.obs.span``)
+    _DECODE_SPAN = "engine.decode_step"
+
     def step(self) -> None:
         """One engine step: expire deadlines, admit (preempting a victim
         to host under pool pressure if armed), run at most one prefill
-        chunk, then one batched decode step for every decoding slot."""
+        chunk, then one batched decode step for every decoding slot.
+
+        Host phase spans (``repro.obs.span``) tile the step on the
+        profiler's clock: ``engine.admit``, ``engine.prefill_chunk``,
+        ``engine.first_token``, the decode span and ``engine.retire``
+        (inside the phase that retires), with each host read that waits
+        on the device in an ``engine.fetch``."""
         self._step_count += 1
         if self.obs.enabled:
             self.obs.set_step(self._step_count)
+        with obs.span("engine.admit"):
+            self._admit()
+        nxt = self.scheduler.next_chunk()
+        if nxt is not None:
+            req, chunk, pos0 = nxt
+            with obs.span("engine.prefill_chunk"):
+                logits = self._dispatch_chunk(req, chunk, pos0)
+            if logits is not None:
+                with obs.span("engine.first_token"):
+                    self._emit_first_token(req, logits)
+        if self.scheduler.decoding:
+            with obs.span(self._DECODE_SPAN):
+                self._decode_step()
+        self.kv_stats["alloc_faults"] = self.scheduler.allocator.faults
+
+    def _admit(self) -> None:
         self._expire_deadlines()
         if self.injector is not None:
             self._inject_step_faults()
@@ -788,51 +816,46 @@ class DecodeEngine:
             else:
                 self._admit_slot(req)
 
-        nxt = self.scheduler.next_chunk()
-        if nxt is not None:
-            req, chunk, pos0 = nxt
-            # profiling is opt-in (Telemetry(profile=True)); the prof
-            # guard keeps the disabled/plain-telemetry hot path untouched
-            prof = self.obs.profile
-            t0 = time.perf_counter() if prof is not None else 0.0
-            tok_arr = jnp.asarray([chunk], jnp.int32)
-            logits, self.caches = self._prefill_chunk(
-                self.params, tok_arr, self.caches,
-                jnp.int32(req.slot), jnp.int32(pos0))
-            if prof is not None:
+    def _dispatch_chunk(self, req: Request, chunk: list, pos0: int):
+        """Dispatch one prefill chunk of ``req``; returns the chunk's
+        logits when it completes the prompt, else None."""
+        first = pos0 == req.prefix_hit
+        if first:
+            req.first_chunk_time = time.perf_counter()
+        # profiling is opt-in (Telemetry(profile=True)); the prof
+        # guard keeps the disabled/plain-telemetry hot path untouched
+        prof = self.obs.profile
+        t0 = time.perf_counter() if prof is not None else 0.0
+        tok_arr = jnp.asarray([chunk], jnp.int32)
+        logits, self.caches = self._prefill_chunk(
+            self.params, tok_arr, self.caches,
+            jnp.int32(req.slot), jnp.int32(pos0))
+        if prof is not None:
+            with obs.span("engine.fetch"):
                 logits.block_until_ready()
-                # lower against the POST-call cache tree: the update is
-                # functional, so shapes (the HLO-cost cache key) match
-                # the consumed input tree exactly
-                prof.record_call(
-                    "prefill_chunk", self._prefill_chunk,
-                    (self.params, tok_arr, self.caches,
-                     jnp.int32(req.slot), jnp.int32(pos0)),
-                    wall_s=time.perf_counter() - t0,
-                    host_bytes=tok_arr.nbytes)
-            self._on_prefill_chunk(req, chunk, pos0)
-            if self.obs.enabled:
-                self.obs.trace.instant("prefill_chunk", rid=req.rid,
-                                       pos0=pos0, tokens=len(chunk))
-            req.last_progress_step = self._step_count
-            # tokens the engine ACTUALLY pushed through the prefill path:
-            # the measured side of the prefix-cache reduction (a cold
-            # engine accumulates every prompt token here, a hit engine
-            # only the uncached remainder)
-            self.kv_stats["prefill_tokens"] += len(chunk)
-            self._account_prefill(pos0 + len(chunk),
-                                  first=pos0 == req.prefix_hit)
-            if self.scheduler.prefill_advance(req, len(chunk)):
-                self._emit_first_token(req, logits)
-
-        if self.scheduler.decoding:
-            if self.obs.enabled and self.obs.wall_clock:
-                t0 = time.perf_counter()
-                self._decode_step()
-                self._h_intertoken.observe(time.perf_counter() - t0)
-            else:
-                self._decode_step()
-        self.kv_stats["alloc_faults"] = self.scheduler.allocator.faults
+            # lower against the POST-call cache tree: the update is
+            # functional, so shapes (the HLO-cost cache key) match
+            # the consumed input tree exactly
+            prof.record_call(
+                "prefill_chunk", self._prefill_chunk,
+                (self.params, tok_arr, self.caches,
+                 jnp.int32(req.slot), jnp.int32(pos0)),
+                wall_s=time.perf_counter() - t0,
+                host_bytes=tok_arr.nbytes)
+        self._on_prefill_chunk(req, chunk, pos0)
+        if self.obs.enabled:
+            self.obs.trace.instant("prefill_chunk", rid=req.rid,
+                                   pos0=pos0, tokens=len(chunk))
+        req.last_progress_step = self._step_count
+        # tokens the engine ACTUALLY pushed through the prefill path:
+        # the measured side of the prefix-cache reduction (a cold
+        # engine accumulates every prompt token here, a hit engine
+        # only the uncached remainder)
+        self.kv_stats["prefill_tokens"] += len(chunk)
+        self._account_prefill(pos0 + len(chunk), first=first)
+        if self.scheduler.prefill_advance(req, len(chunk)):
+            return logits
+        return None
 
     def _admit_slot(self, req: Request) -> None:
         """Device-side half of a fresh admission: point the slot's table
@@ -1083,7 +1106,8 @@ class DecodeEngine:
         in_before = self.swap.stats["restored_bytes_total"]
         self.caches = self.swap.swap_in(req.rid, self.caches, req.blocks)
         if prof is not None:
-            jax.block_until_ready(self.caches)
+            with obs.span("engine.fetch"):
+                jax.block_until_ready(self.caches)
             prof.record(
                 "swap_in", wall_s=time.perf_counter() - t0,
                 host_bytes=self.swap.stats["restored_bytes_total"]
@@ -1134,7 +1158,8 @@ class DecodeEngine:
         snap = paged.concat_block_snapshots(snaps)
         self.caches = paged.restore_blocks(self.caches, blocks, snap)
         if prof is not None:
-            jax.block_until_ready(self.caches)
+            with obs.span("engine.fetch"):
+                jax.block_until_ready(self.caches)
             prof.record(
                 "prefix_promote", wall_s=time.perf_counter() - t0,
                 host_bytes=sum(int(a.nbytes) for a in snap.values()))
@@ -1185,25 +1210,27 @@ class DecodeEngine:
         ``self.quarantined`` for a degraded-path retry
         (``repro.serving.faults.FailoverServer``) instead of letting it
         poison the batch."""
-        self.kv_stats["guard_trips"] += 1
-        req.error = reason
-        if self.obs.enabled:
-            self.obs.trace.instant("guard_trip", rid=req.rid, reason=reason)
-            self._close_span(req)
-            self.obs.trace.instant("quarantined", rid=req.rid,
-                                   emitted=len(req.output))
-        self._on_drop(req)
-        alloc = self.scheduler.allocator
-        scrub = [b for b in req.blocks if alloc.refcount(b) == 1]
-        if scrub:
-            self.caches = paged.zero_blocks(self.caches, scrub)
-        slot = req.slot
-        dropped = self.scheduler.drop(req, "quarantined")
-        assert dropped, f"quarantine of request {req.rid} not in flight"
-        self.caches = self._reset_slot(self.caches, jnp.int32(slot),
-                                       self._null_row)
-        req.slot = None
-        self.quarantined.append(req)
+        with obs.span("engine.retire"):
+            self.kv_stats["guard_trips"] += 1
+            req.error = reason
+            if self.obs.enabled:
+                self.obs.trace.instant("guard_trip", rid=req.rid,
+                                       reason=reason)
+                self._close_span(req)
+                self.obs.trace.instant("quarantined", rid=req.rid,
+                                       emitted=len(req.output))
+            self._on_drop(req)
+            alloc = self.scheduler.allocator
+            scrub = [b for b in req.blocks if alloc.refcount(b) == 1]
+            if scrub:
+                self.caches = paged.zero_blocks(self.caches, scrub)
+            slot = req.slot
+            dropped = self.scheduler.drop(req, "quarantined")
+            assert dropped, f"quarantine of request {req.rid} not in flight"
+            self.caches = self._reset_slot(self.caches, jnp.int32(slot),
+                                           self._null_row)
+            req.slot = None
+            self.quarantined.append(req)
 
     @property
     def num_active(self) -> int:
@@ -1251,7 +1278,7 @@ class DecodeEngine:
         subsumes the legacy dict value-for-value — the single source of
         truth stays the engine's own accounting), swap-pool counters,
         derived-rate gauges, and — when telemetry is attached — the live
-        TTFT / queue-wait / inter-token histograms."""
+        TTFT / queue-wait histograms."""
         reg = obs.MetricsRegistry()
         for key, val in self.kv_stats.items():
             c = reg.counter(key, unit=self._METRIC_UNITS.get(key, ""),
@@ -1313,10 +1340,13 @@ class DecodeEngine:
         """Greedy argmax unless the request opted into sampling. ``row`` is
         the device-side logit row."""
         if req.temperature <= 0.0:
-            return int(jnp.argmax(row))
-        return int(_sample_rows(row[None],
-                                jnp.asarray([req.temperature], jnp.float32),
-                                self._sample_key(req)[None], req.top_k)[0])
+            tok = jnp.argmax(row)
+        else:
+            tok = _sample_rows(row[None],
+                               jnp.asarray([req.temperature], jnp.float32),
+                               self._sample_key(req)[None], req.top_k)[0]
+        with obs.span("engine.fetch"):
+            return int(tok)
 
     def _emit_first_token(self, req: Request, logits: jax.Array) -> None:
         """Final prefill chunk's logits yield the request's first token."""
@@ -1326,7 +1356,8 @@ class DecodeEngine:
         row = logits.reshape(1, -1)
         tok_arr = jnp.asarray([tok], jnp.int32)
         stats = _logit_stats(row, tok_arr)
-        host_stats = {k: np.asarray(v) for k, v in stats.items()}
+        with obs.span("engine.fetch"):
+            host_stats = {k: np.asarray(v) for k, v in stats.items()}
         if prof is not None:
             # a named ops.* dispatch: the first-token stats pass is the
             # one _logit_stats launch the fused decode step doesn't fold
@@ -1350,7 +1381,7 @@ class DecodeEngine:
         if self.obs.enabled:
             self._h_ttft.observe(self._step_count - req.submit_step)
         req.output.append(tok)
-        req.logprobs.append(float(stats["logprob"][0]))
+        req.logprobs.append(float(host_stats["logprob"][0]))
         req.last_progress_step = self._step_count
         self._next_tokens[req.slot, 0] = tok
         if self._finished(req, tok):
@@ -1395,7 +1426,9 @@ class DecodeEngine:
             # temperature/top-k sampling: one vmapped launch per distinct
             # top_k (usually one total) — draws stay device-side, only the
             # chosen indices cross
-            toks = np.asarray(_greedy_tokens(rows)).copy()
+            greedy = _greedy_tokens(rows)
+            with obs.span("engine.fetch"):
+                toks = np.asarray(greedy).copy()
             by_k: dict[int, list] = {}
             for slot, req in sampled.items():
                 by_k.setdefault(req.top_k, []).append((slot, req))
@@ -1409,7 +1442,8 @@ class DecodeEngine:
                     jnp.stack([self._sample_key(r) for _, r in items]),
                     top_k)
                 draws = _sample_rows(*sample_args)
-                toks[slots] = np.asarray(draws)
+                with obs.span("engine.fetch"):
+                    toks[slots] = np.asarray(draws)
                 if prof is not None:
                     prof.record_call(
                         "ops.sample_rows", _sample_rows, sample_args,
@@ -1419,21 +1453,24 @@ class DecodeEngine:
             # fused logprob/metric pass over the final token choices; only
             # (B,)-sized arrays ever reach the host
             stats = _logit_stats(rows, tokens_dev)
-            tokens = np.asarray(tokens_dev)
-            self.last_logit_stats = {k: np.asarray(v)
-                                     for k, v in stats.items()}
+            with obs.span("engine.fetch"):
+                tokens = np.asarray(tokens_dev)
+                self.last_logit_stats = {k: np.asarray(v)
+                                         for k, v in stats.items()}
         elif injected:
             # choice + stats must see the poisoned rows, not the fused
             # pre-injection packing
             tokens_dev = _greedy_tokens(rows)
             stats = _logit_stats(rows, tokens_dev)
-            tokens = np.asarray(tokens_dev)
-            self.last_logit_stats = {k: np.asarray(v)
-                                     for k, v in stats.items()}
+            with obs.span("engine.fetch"):
+                tokens = np.asarray(tokens_dev)
+                self.last_logit_stats = {k: np.asarray(v)
+                                         for k, v in stats.items()}
         else:
             # all-greedy: the fused decode launch already packed tokens +
             # stats — ONE host transfer covers the step
-            packed = np.asarray(packed_dev)
+            with obs.span("engine.fetch"):
+                packed = np.asarray(packed_dev)
             tokens = packed[0].astype(np.int32)
             self.last_logit_stats = {k: packed[i + 1]
                                      for i, k in enumerate(_STAT_KEYS)}
@@ -1477,17 +1514,18 @@ class DecodeEngine:
                 or (req.eos_id is not None and tok == req.eos_id))
 
     def _retire(self, req: Request) -> None:
-        slot = req.slot
-        if self.obs.enabled:
-            self.obs.trace.end("decode", rid=req.rid)
-            self.obs.trace.instant("retired", rid=req.rid,
-                                   emitted=len(req.output))
-        self._on_retire(req)
-        self.scheduler.retire(req)
-        # Point the slot's tables back at the null block so the next
-        # batched steps' stray writes can't touch re-allocated blocks.
-        self.caches = self._reset_slot(self.caches, jnp.int32(slot),
-                                       self._null_row)
+        with obs.span("engine.retire"):
+            slot = req.slot
+            if self.obs.enabled:
+                self.obs.trace.end("decode", rid=req.rid)
+                self.obs.trace.instant("retired", rid=req.rid,
+                                       emitted=len(req.output))
+            self._on_retire(req)
+            self.scheduler.retire(req)
+            # Point the slot's tables back at the null block so the next
+            # batched steps' stray writes can't touch re-allocated blocks.
+            self.caches = self._reset_slot(self.caches, jnp.int32(slot),
+                                           self._null_row)
 
     # ------------------------------------------------------- accounting ---
 
@@ -1539,6 +1577,8 @@ class SpecDecodeEngine(DecodeEngine):
     stay keyed on (seed, emit index) — reproducible and batch-invariant —
     with the emitted marginal exactly the target distribution.
     """
+
+    _DECODE_SPAN = "engine.verify_step"
 
     def __init__(self, cfg: ModelConfig, params, *, proposer,
                  spec_k: int = 4, **kw):
@@ -1610,7 +1650,8 @@ class SpecDecodeEngine(DecodeEngine):
                                           self._step_count))
         if not stalled:
             try:
-                drafts, qdists = self.proposer.propose(decoding, ks)
+                with obs.span("engine.propose"):
+                    drafts, qdists = self.proposer.propose(decoding, ks)
             except ProposerStallError:
                 stalled = True
         if stalled:
@@ -1633,16 +1674,20 @@ class SpecDecodeEngine(DecodeEngine):
             victim = self.injector.choose("logit_nan", self._step_count,
                                           len(decoding))
             logits = logits.at[victim].set(jnp.nan)
-        argmax = np.asarray(jnp.argmax(logits, axis=-1))       # [B, C]
+        argmax_dev = jnp.argmax(logits, axis=-1)
+        with obs.span("engine.fetch"):
+            argmax = np.asarray(argmax_dev)                    # [B, C]
         # Greedy batches keep the host-transfer discipline (only the
         # [B, C] argmax crosses). Exact accept/residual math for SAMPLED
         # requests currently pulls the full [B, C, V] rows — fine at this
         # repo's CPU-test vocab sizes, but a device-side rejection sampler
         # (the _sample_rows treatment applied to accept/residual draws)
         # is what a large-vocab deployment needs; see ROADMAP.
-        sampled = any(r.temperature > 0.0 for r in decoding)
-        rows = (np.asarray(logits[:len(decoding)], np.float32)
-                if sampled else None)
+        rows = None
+        if any(r.temperature > 0.0 for r in decoding):
+            head = logits[:len(decoding)]
+            with obs.span("engine.fetch"):
+                rows = np.asarray(head, np.float32)
 
         emitted_all: list[list[int]] = []
         accepted: list[int] = []
@@ -1666,9 +1711,10 @@ class SpecDecodeEngine(DecodeEngine):
             chosen[i, :len(emitted)] = emitted
         stats = _logit_stats(logits.reshape(-1, logits.shape[-1]),
                              jnp.asarray(chosen.reshape(-1), jnp.int32))
-        logprobs = np.asarray(stats["logprob"]).reshape(tokens.shape)
-        self.last_logit_stats = {
-            k: np.asarray(v).reshape(tokens.shape) for k, v in stats.items()}
+        with obs.span("engine.fetch"):
+            self.last_logit_stats = {k: np.asarray(v).reshape(tokens.shape)
+                                     for k, v in stats.items()}
+        logprobs = self.last_logit_stats["logprob"]
 
         # rollback: rejected suffixes disappear by length bookkeeping only
         lens_pad = np.full((self.max_slots,), new_lens[0], np.int32)

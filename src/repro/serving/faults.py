@@ -196,7 +196,9 @@ class FaultInjector:
                 if f.step != step:
                     continue
             elif f.rate > 0.0:
-                draw = float(jax.random.uniform(self._key(site, step)))
+                u = jax.random.uniform(self._key(site, step))
+                with obs.span("engine.fetch"):
+                    draw = float(u)
                 if draw >= f.rate:
                     continue
             elif f.fired:
@@ -212,8 +214,10 @@ class FaultInjector:
     def choose(self, site: str, step: int, n: int) -> int:
         """Keyed victim index in [0, n) — deterministic per (seed, site,
         step), recorded in the log entry for replay checks."""
-        pick = int(jax.random.randint(
-            jax.random.fold_in(self._key(site, step), 1), (), 0, n))
+        pick_dev = jax.random.randint(
+            jax.random.fold_in(self._key(site, step), 1), (), 0, n)
+        with obs.span("engine.fetch"):
+            pick = int(pick_dev)
         if self.log and self.log[-1][:2] == (step, site):
             self.log[-1][2]["choice"] = pick
         return pick

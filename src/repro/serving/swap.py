@@ -86,8 +86,9 @@ class KVSwap:
 
     def swap_out(self, rid: int, caches, blocks: list[int]) -> None:
         assert rid not in self._store, f"request {rid} already swapped out"
-        snap = {k: np.asarray(v)
-                for k, v in paged.extract_blocks(caches, blocks).items()}
+        blocks_dev = paged.extract_blocks(caches, blocks)
+        with obs.span("engine.fetch"):
+            snap = {k: np.asarray(v) for k, v in blocks_dev.items()}
         self._store[rid] = snap
         self._nblocks[rid] = len(blocks)
         self.stats["swapped_out_blocks"] += len(blocks)
@@ -184,8 +185,9 @@ class PrefixSpill:
             # same path == same bits; replace, keeping residency exact
             self._store.pop(key)
             self.stats["host_bytes"] -= self._nbytes.pop(key)
-        snap = {k: np.asarray(v)
-                for k, v in self._snapshot_fn([block]).items()}
+        block_dev = self._snapshot_fn([block])
+        with obs.span("engine.fetch"):
+            snap = {k: np.asarray(v) for k, v in block_dev.items()}
         nbytes = sum(a.nbytes for a in snap.values())
         self._store[key] = snap
         self._nbytes[key] = nbytes
