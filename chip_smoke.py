@@ -15,7 +15,10 @@ starts no child process. Phases:
   d  SpecDecodeEngine with the n-gram proposer (verify walk at W = k + 1)
   e  the compiled decode, verify and logit-stats programs hold the Pallas
      kernels as ``tpu_custom_call``s, so no interpret mode and no gather
-     path ran on the chip
+     path ran on the chip; the compiled decode and prefill-chunk programs
+     (bf16 and int8 pools) copy no K/V payload pool, a layer's or the
+     stack, into a non-default layout: the pools are read and written as
+     stored (int8's scale tiles still are, and are counted)
   f  reference: on the same caches, the kernel path against the
      gather + flash formulation, for the first decode step and a verify
      window; ``_logit_stats`` against ``jax.nn.logsumexp`` and jnp
@@ -140,9 +143,10 @@ def serve(label: str, engine, requests, cfg, dev) -> None:
         f"{lp.mean():.3f}")
 
 
-def kernels_in(label: str, jitted, args, want: tuple[str, ...]) -> None:
+def kernels_in(label: str, jitted, args, want: tuple[str, ...]) -> str:
     """Compile ``jitted`` at ``args`` and require a ``tpu_custom_call``
-    for each kernel name in ``want`` (Pallas names the call site)."""
+    for each kernel name in ``want`` (Pallas names the call site).
+    Returns the optimized HLO text."""
     t0 = time.perf_counter()
     compiled = jitted.lower(*args).compile()
     text = compiled.as_text()
@@ -159,16 +163,55 @@ def kernels_in(label: str, jitted, args, want: tuple[str, ...]) -> None:
     if missing:
         raise RuntimeError(f"(e) {label}: no tpu_custom_call for {missing}")
     log(f"smoke (e) {label}: tpu_custom_call "
-        + ", ".join(f"{n} x{found[n]}" for n in want)
+        + (", ".join(f"{n} x{found[n]}" for n in want) or "none required")
         + f" (compile {time.perf_counter() - t0:.1f} s; arguments "
         f"{mem.argument_size_in_bytes / 2**30:.2f} GiB, output "
         f"{mem.output_size_in_bytes / 2**30:.2f} GiB, temporaries "
         f"{mem.temp_size_in_bytes / 2**30:.2f} GiB)")
+    return text
+
+
+def pool_layouts(label: str, text: str, caches) -> None:
+    """Count the copies in optimized HLO ``text`` whose shape is a pool
+    layer's or a stacked pool's. A K/V payload pool copied into a
+    non-default layout is a relayout of a whole pool inside the launch,
+    and fails the phase; the quantized scale tiles [nb, bs, hkv] (hkv of
+    128 lanes) are still re-laid out, and are only counted."""
+    from jax.tree_util import tree_flatten_with_path
+    from repro.ecm.hlo_cost import pool_copies
+    from repro.models.paged import POOL_KEYS
+    leaves: dict[tuple, str] = {}       # one layer's shape -> pool leaves
+    for path, leaf in tree_flatten_with_path(caches)[0]:
+        name = str(getattr(path[-1], "key", path[-1]))
+        if name in POOL_KEYS:
+            shape = leaf.shape[1:]
+            leaves[shape] = "/".join(filter(None, (leaves.get(shape), name)))
+    nb = next(iter(leaves))[0]
+    copies = pool_copies(text, nb)
+    relaid: dict[str, list[str]] = {}
+    for c in copies:
+        if not c.default:
+            names = next((n for shape, n in leaves.items()
+                          if c.dims[-len(shape):] == shape), "?")
+            relaid.setdefault(names, []).append(f"{c.name} {c.dims}")
+    log(f"smoke (e) {label}: {len(copies)} pool-shaped copies, into a "
+        f"non-default layout: "
+        + (", ".join(f"{n} x{len(v)}" for n, v in relaid.items()) or "0"))
+    payload = {n: v for n, v in relaid.items()
+               if n == "?" or "kpool" in n or "vpool" in n}
+    if payload:
+        raise RuntimeError(f"(e) {label}: pool relayouts {payload}")
 
 
 def decode_args(engine):
     import jax.numpy as jnp
     return (engine.params, jnp.asarray(engine._next_tokens), engine.caches)
+
+
+def prefill_chunk_args(engine):
+    import jax.numpy as jnp
+    return (engine.params, jnp.zeros((1, CHUNK), jnp.int32), engine.caches,
+            jnp.int32(0), jnp.int32(0))
 
 
 def verify_args(engine):
@@ -208,8 +251,16 @@ def phase_engine(label, cfg, params, dev, *, spec: bool):
                     jnp.zeros((SLOTS * window,), jnp.int32)),
                    ("fused_reduce_rows",))
     else:
-        kernels_in(f"decode[{label}]", eng._decode, decode_args(eng),
-                   ("paged_attention", "fused_reduce_rows"))
+        pool_layouts(f"decode[{label}]",
+                     kernels_in(f"decode[{label}]", eng._decode,
+                                decode_args(eng),
+                                ("paged_attention", "fused_reduce_rows")),
+                     eng.caches)
+        pool_layouts(f"prefill_chunk[{label}]",
+                     kernels_in(f"prefill_chunk[{label}]",
+                                eng._prefill_chunk, prefill_chunk_args(eng),
+                                ()),
+                     eng.caches)
     del eng
 
 

@@ -214,6 +214,12 @@ def cache_spec_for(struct: jax.ShapeDtypeStruct, mesh: Mesh,
     return P(*dims)
 
 
+def _kv_heads(cfg) -> int:
+    """KV heads of the model's GQA pools (hybrid: its shared block's)."""
+    return (cfg.hybrid.num_kv_heads if cfg.family == "hybrid"
+            else cfg.num_kv_heads)
+
+
 def serve_cache_shardings(cfg, cache_struct, mesh: Mesh, global_batch: int):
     """Sharding pytree for a model's stacked decode caches.
 
@@ -225,13 +231,17 @@ def serve_cache_shardings(cfg, cache_struct, mesh: Mesh, global_batch: int):
     so they replicate except for a model split on a divisible feature
     dim; block tables and lengths shard over batch only.
 
+    GQA payload pools are lane-dense, [stack, NB, bs, H * D]: their
+    feature dim gets "model" only where the kv-head count H divides by
+    the axis, so every rank holds whole heads.
+
     Scale-array rule (quantized pools, repro.quant): the per-block scale
     tiles ("kscale"/"vscale" [stack, NB, bs, H], MLA's "c_kv_scale"/
     "k_rope_scale" [stack, NB, bs]) are pools too (POOL_KEYS) and take
     the same branch — block axis over the data axes when divisible,
     never the within-block position axis, and the head dim gets "model"
-    exactly when the value pool's head dim does, so a rank always holds
-    a block's payload and its scales together.
+    exactly when the value pool's heads do, so a rank always holds a
+    block's payload and its scales together.
     """
     from jax.tree_util import tree_map_with_path
 
@@ -256,8 +266,11 @@ def serve_cache_shardings(cfg, cache_struct, mesh: Mesh, global_batch: int):
             if ba and s.shape[lead] % _axis_size(mesh, ba) == 0:
                 dims[lead] = ba if len(ba) > 1 else ba[0]
             model = mesh.shape.get("model", 1)
+            # GQA payloads [stack, NB, bs, hkv * d] split whole heads only
+            heads = _kv_heads(cfg) if name in ("kpool", "vpool") else None
             for i in range(lead + 2, len(s.shape)):
-                if model > 1 and s.shape[i] % model == 0:
+                if model > 1 and s.shape[i] % model == 0 and (
+                        heads is None or heads % model == 0):
                     dims[i] = "model"
                     break
             return NamedSharding(mesh, P(*dims))

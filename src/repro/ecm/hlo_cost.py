@@ -33,6 +33,7 @@ from __future__ import annotations
 
 import re
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -422,3 +423,33 @@ def analyze(text: str, *, default_group: int = 1) -> HloCost:
                 cost.weighted_collective_bytes += vol * factor * frac * m
 
     return cost
+
+
+_LAYOUT_RE = re.compile(r"\[([0-9,]*)\]\{([0-9,]*)")
+
+
+class PoolCopy(NamedTuple):
+    name: str             # the copy instruction
+    dims: tuple           # its result's shape
+    layout: str           # its result's minor-to-major order
+    default: bool         # whether that is the default row-major order
+
+
+def pool_copies(text: str, num_blocks: int) -> list[PoolCopy]:
+    """Every ``copy`` in optimized HLO ``text`` whose result is shaped like
+    a KV pool with ``num_blocks`` blocks: one layer's [nb, ...] or
+    [1, nb, ...], or a stacked pool [L, nb, ...]. A serving launch that
+    re-lays a pool out shows a copy into a non-default layout here."""
+    out = []
+    for comp in _parse_computations(text).values():
+        for ins in comp.instrs:
+            m = _LAYOUT_RE.search(ins.shape)
+            if ins.op != "copy" or m is None:
+                continue
+            dims = tuple(int(d) for d in m.group(1).split(",") if d)
+            if num_blocks not in dims[:2]:
+                continue
+            order = [int(d) for d in m.group(2).split(",") if d]
+            out.append(PoolCopy(ins.name, dims, m.group(2),
+                                order == sorted(order, reverse=True)))
+    return out

@@ -207,9 +207,9 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array | None,
     ``q_offsets + w``; defaults to ``lens - W``, i.e. the window was just
     appended to the cache. Returns [B, W, Hq, Dv].
 
-    GQA pools: kpool/vpool [nb, bs, Hkv, D]; quantized pools (int8/fp8)
-    pass kscale/vscale [nb, bs, Hkv] and the kernel folds the scales
-    post-dot into the compensated streams.
+    GQA pools: kpool/vpool [nb, bs, Hkv * D], as the cache stores them;
+    quantized pools (int8/fp8) pass kscale/vscale [nb, bs, Hkv] and the
+    kernel folds the scales post-dot into the compensated streams.
 
     MLA latents: pass the c_kv pool as ``kpool`` [nb, bs, C] with
     ``vpool=None`` (the value IS the latent block), the rope stream via
@@ -220,10 +220,11 @@ def paged_attention(q: jax.Array, kpool: jax.Array, vpool: jax.Array | None,
     assert q.ndim == 4, q.shape
     assert block_table.shape[0] == q.shape[0] == lens.shape[0]
     if q_rope is None:
-        assert vpool is not None and kpool.ndim == 4, kpool.shape
+        assert vpool is not None and kpool.ndim == 3, kpool.shape
         if kscale is not None:
-            assert kscale.shape == kpool.shape[:3], (kscale.shape,
-                                                     kpool.shape)
+            assert (kscale.shape[:2] == kpool.shape[:2] and kpool.shape[2]
+                    == kscale.shape[2] * q.shape[-1]), (kscale.shape,
+                                                        kpool.shape)
     else:
         assert vpool is None and rope_pool is not None and kpool.ndim == 3
         assert scale is not None, "MLA needs the explicit softmax scale"

@@ -43,11 +43,14 @@ repeated at the attention layer, parameterized by
 
 The walk: grid (batch, table slot) with scalar prefetch — the
 block-table index feeds the BlockSpec index map, so each grid step DMAs
-exactly one pool block, for all kv heads at once, from HBM. Pools are
-viewed lane-dense as [nb, bs, hkv * d] (a free reshape of the
-[nb, bs, hkv, d] layout every cache module keys on), so each block's
-trailing two dims are the array's own: the TPU lowering refuses a block
-whose second-minor dim is 1 of hkv, which a per-head grid axis needs.
+exactly one pool block, for all kv heads at once, from HBM. GQA pools
+are stored lane-dense as [nb, bs, hkv * d] (``attention.gqa_cache_spec``)
+and the kernel reads them as they are stored, so each block's trailing
+two dims are the array's own: the TPU lowering refuses a block whose
+second-minor dim is 1 of hkv, which a per-head grid axis needs. A
+[nb, bs, hkv, d] pool is not those bytes: the TPU tiles the last two
+dims, and for d under 128 lanes it pads d or stores the dims in another
+order, so viewing such a pool lane-dense re-lays it out in every launch.
 The kv heads are a static loop inside the step, each reading its lane
 window. The online-softmax normalizer and output accumulator keep
 compensated (sum, carry) stream pairs per head (``kahan.neumaier_step``,
@@ -156,7 +159,7 @@ def paged_grid_spec(b: int, mb: int, bs: int, hkv: int, rows: int,
     q_offsets) scalar prefetch; each step DMAs one pool block for ALL kv
     heads. ``q_dims`` gives the trailing dim of each query operand
     ([b, hkv, rows, d]); ``kv_widths`` the trailing dim of each pool
-    operand, viewed lane-dense as [nb, bs, hkv * d]; ``n_scales`` appends
+    operand, stored lane-dense as [nb, bs, hkv * d]; ``n_scales`` appends
     that many [nb, bs, hkv] scale-tile operands following the same
     table-indexed walk — ONE scale DMA per block, not per element. Every
     block's trailing two dims are the array's own, the layout the TPU
@@ -284,16 +287,20 @@ def paged_attention_pallas(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
                            interpret: bool = False) -> jax.Array:
     """W query rows per sequence against (optionally quantized) paged KV.
 
-    q: [B, W, Hq, D]; kpool/vpool: [nb, bs, Hkv, Dk/Dv] — bf16/f32, or
-    int8/fp8 with kscale/vscale [nb, bs, Hkv] per-(token-row, head) f32
-    scales; block_table: [B, mb] int32; lens: [B] total valid keys (the
-    window's K/V must already be scattered); q_offsets: [B] absolute
-    position of query row 0 (row w attends keys < q_offsets + 1 + w; for
-    decode q_offsets == lens - 1). Returns [B, W, Hq, Dv] in q's dtype.
+    q: [B, W, Hq, D]; kpool/vpool: [nb, bs, Hkv * D], the stored
+    lane-dense layout — bf16/f32, or int8/fp8 with kscale/vscale
+    [nb, bs, Hkv] per-(token-row, head) f32 scales; the kv head count is
+    the pool width over q's head dim. block_table: [B, mb] int32; lens:
+    [B] total valid keys (the window's K/V must already be scattered);
+    q_offsets: [B] absolute position of query row 0 (row w attends keys
+    < q_offsets + 1 + w; for decode q_offsets == lens - 1). Returns
+    [B, W, Hq, Dv] in q's dtype.
     """
     b, w, hq, d = q.shape
-    nb, bs, hkv, dk = kpool.shape
-    dv = vpool.shape[-1]
+    _, bs, kwidth = kpool.shape
+    hkv, dk = kwidth // d, d
+    assert hkv * d == kwidth and hq % hkv == 0, (q.shape, kpool.shape)
+    dv = vpool.shape[-1] // hkv
     mb = block_table.shape[1]
     groups = hq // hkv
     rows = w * groups
@@ -311,9 +318,7 @@ def paged_attention_pallas(q: jax.Array, kpool: jax.Array, vpool: jax.Array,
         _super_kernel, mla=False, quant=quant,
         scale=d ** -0.5 if scale is None else scale, bs=bs, rows=rows_pad,
         groups=groups, hkv=hkv, dk=dk, dv=dv)
-    # lane-dense pool views: a free reshape of the [nb, bs, Hkv, D] pools
-    args = [block_table, lens, q_offsets, qg,
-            kpool.reshape(nb, bs, hkv * dk), vpool.reshape(nb, bs, hkv * dv)]
+    args = [block_table, lens, q_offsets, qg, kpool, vpool]
     if quant:
         args += [kscale, vscale]
     out = pl.pallas_call(

@@ -15,9 +15,12 @@ tests/test_models_attention.py).
 Decode caches are block-paged (see ``repro.models.paged``): K/V live in a
 shared block pool indexed through per-sequence block tables, so a sequence
 only occupies (and the decode gather only touches) the blocks its actual
-length needs. ``flash_attention`` takes a dynamic ``q_offset`` so chunked
-prefill can extend a paged cache incrementally — queries at absolute
-positions ``q_offset..q_offset+C-1`` against the gathered prefix+chunk.
+length needs. Each GQA pool is stored lane-dense, [nb, bs, hkv * d], the
+layout the superkernel reads; writers flatten the new rows' heads and
+readers split the heads of gathered rows only. ``flash_attention`` takes
+a dynamic ``q_offset`` so chunked prefill can extend a paged cache
+incrementally — queries at absolute positions ``q_offset..q_offset+C-1``
+against the gathered prefix+chunk.
 The serving decode dispatches per backend (``paged_kernel_enabled``): the
 Pallas block-table kernel ``repro.kernels.paged_attention`` on TPU; off
 TPU, decode and verify run the chunked-prefill formulation itself
@@ -383,7 +386,8 @@ def gqa_prefill(p: dict, x: Array, cfg: AttnConfig, layout: PagedLayout
     """One-shot prefill: forward + emit a block-paged KV cache.
 
     The computed K/V rows are re-laid-out into a per-batch identity-table
-    pool (a pure reshape — the later block gather reproduces them bitwise).
+    pool in the stored lane-dense layout [nb, bs, hkv * d] (a pure reshape —
+    the later block gather reproduces them bitwise).
     Under a quantized ``kv_dtype`` the rows are quantized per (token, head)
     first and the prefill attention runs over the *dequantized* values —
     the cache IS the quantized data, so every consumer (this prefill, later
@@ -407,36 +411,47 @@ def gqa_prefill(p: dict, x: Array, cfg: AttnConfig, layout: PagedLayout
     out = flash_attention(q, k, v, causal=cfg.causal, q_chunk=cfg.q_chunk,
                           kv_chunk=cfg.kv_chunk, kahan_acc=cfg.kahan_acc,
                           causal_packing=cfg.causal_packing)
-    cache = {"kpool": paged.pool_from_rows(k_store, layout),
-             "vpool": paged.pool_from_rows(v_store, layout),
+    cache = {"kpool": paged.pool_from_rows(_flat_heads(k_store), layout),
+             "vpool": paged.pool_from_rows(_flat_heads(v_store), layout),
              "block_table": paged.identity_table(b, layout),
              "len": jnp.full((b,), l, jnp.int32), **scale_pools}
     return common.dense(out.reshape(b, l, -1), p["wo"]), cache
 
 
+def _flat_heads(x: Array) -> Array:
+    """[..., hkv, d] -> [..., hkv * d]: K/V rows in the pools' stored
+    lane-dense layout."""
+    return x.reshape(x.shape[:-2] + (-1,))
+
+
 def _scatter_kv(cache: dict, k: Array, v: Array,
                 fmt: qcore.QuantFormat | None, scatter_fn) -> dict:
-    """Append K/V payloads (and, when quantized, their per-(token, head)
-    scale tiles) through ``scatter_fn(pool, vals)`` — the ONE place the
-    quantize-on-write happens for both the token and chunk append paths."""
+    """Append K/V payloads [..., hkv, d] (and, when quantized, their
+    per-(token, head) scale tiles) through ``scatter_fn(pool, vals)`` — the
+    ONE place the quantize-on-write happens for both the token and chunk
+    append paths. Rows are quantized per (token, head) first, then
+    flattened to the pools' [..., hkv * d] layout."""
     if fmt is None:
-        return {"kpool": scatter_fn(cache["kpool"], k),
-                "vpool": scatter_fn(cache["vpool"], v)}
+        return {"kpool": scatter_fn(cache["kpool"], _flat_heads(k)),
+                "vpool": scatter_fn(cache["vpool"], _flat_heads(v))}
     qk, sk = qcore.quantize_lastdim(k, fmt)
     qv, sv = qcore.quantize_lastdim(v, fmt)
-    return {"kpool": scatter_fn(cache["kpool"], qk),
-            "vpool": scatter_fn(cache["vpool"], qv),
+    return {"kpool": scatter_fn(cache["kpool"], _flat_heads(qk)),
+            "vpool": scatter_fn(cache["vpool"], _flat_heads(qv)),
             "kscale": scatter_fn(cache["kscale"], sk),
             "vscale": scatter_fn(cache["vscale"], sv)}
 
 
 def _gather_kv(pools: dict, table: Array, fmt: qcore.QuantFormat | None,
-               dtype) -> tuple[Array, Array]:
-    """Materialize virtual K/V rows from the pools — dequantizing to
-    ``dtype`` when the pools are quantized (every reader sees exactly what
-    the cache stores)."""
-    k = paged.gather_blocks(pools["kpool"], table)
-    v = paged.gather_blocks(pools["vpool"], table)
+               dtype, hkv: int) -> tuple[Array, Array]:
+    """Materialize virtual K/V rows [B, mb*bs, hkv, d] from the pools —
+    dequantizing to ``dtype`` when the pools are quantized (every reader
+    sees exactly what the cache stores). Only the gathered rows are
+    reshaped to split the heads, never the pool."""
+    def rows(name):
+        g = paged.gather_blocks(pools[name], table)    # [B, mb*bs, hkv*d]
+        return g.reshape(g.shape[:-1] + (hkv, -1))
+    k, v = rows("kpool"), rows("vpool")
     if fmt is None:
         return k, v
     return (qcore.dequantize_lastdim(
@@ -487,7 +502,8 @@ def gqa_decode(p: dict, x: Array, cfg: AttnConfig, cache: dict
             kscale=pools.get("kscale"),
             vscale=pools.get("vscale")).astype(x.dtype)
     else:
-        k, v = _gather_kv(pools, table, fmt, x.dtype)  # [B, mb*bs, H, D]
+        k, v = _gather_kv(pools, table, fmt, x.dtype,
+                          cfg.num_kv_heads)            # [B, mb*bs, H, D]
         out = flash_attention(q, k, v, causal=cfg.causal,
                               q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
                               kahan_acc=cfg.kahan_acc,
@@ -518,7 +534,8 @@ def gqa_prefill_chunk(p: dict, x: Array, cfg: AttnConfig, cache: dict,
     pools = _scatter_kv(
         cache, k_new[0], v_new[0], fmt,
         lambda pool, vals: paged.scatter_chunk(pool, table_row, pos0, vals))
-    k, v = _gather_kv(pools, table_row[None], fmt, x.dtype)  # [1,mb*bs,H,D]
+    k, v = _gather_kv(pools, table_row[None], fmt, x.dtype,
+                      cfg.num_kv_heads)                # [1, mb*bs, H, D]
     out = flash_attention(q, k, v, causal=cfg.causal, q_chunk=cfg.q_chunk,
                           kv_chunk=cfg.kv_chunk, kahan_acc=cfg.kahan_acc,
                           q_offset=pos0, kv_len=pos0 + c)
@@ -567,7 +584,8 @@ def gqa_verify_chunk(p: dict, x: Array, cfg: AttnConfig, cache: dict,
         # width-1 decode step at its position (spec == non-spec greedy
         # streams) AND bitwise the prefill of the same token — the one
         # formulation the session-KV parity contract rests on.
-        k, v = _gather_kv(pools, tables, fmt, x.dtype)  # [S, mb*bs, H, D]
+        k, v = _gather_kv(pools, tables, fmt, x.dtype,
+                          cfg.num_kv_heads)            # [S, mb*bs, H, D]
         out = flash_attention(q, k, v, causal=cfg.causal,
                               q_chunk=cfg.q_chunk, kv_chunk=cfg.kv_chunk,
                               kahan_acc=cfg.kahan_acc,
@@ -582,7 +600,11 @@ def gqa_cache_spec(batch: int, layout: PagedLayout, cfg: AttnConfig,
     nb = (paged.default_num_blocks(layout, batch) if num_blocks is None
           else num_blocks)
     fmt = qcore.get_format(cfg.kv_dtype)
-    pool = (nb, layout.block_size, cfg.num_kv_heads, cfg.head_dim)
+    # lane-dense [nb, bs, hkv * d]: the layout the paged-attention
+    # superkernel reads. The TPU tiles a pool's last two dims, so a
+    # [.., hkv, d] pool with d under 128 lanes is stored in other bytes
+    # and every launch would re-lay each layer's pool out
+    pool = (nb, layout.block_size, cfg.num_kv_heads * cfg.head_dim)
     spec = {"kpool": jax.ShapeDtypeStruct(pool, dtype if fmt is None
                                           else fmt.storage),
             "vpool": jax.ShapeDtypeStruct(pool, dtype if fmt is None

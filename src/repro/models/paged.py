@@ -11,6 +11,12 @@ length. The paged layout instead carves the cache into fixed-size token
   block_table  [B, max_blocks] int32           per-slot pool-block indices
   len          [B] int32                       valid tokens per slot
 
+The pool's trailing dims are the cache module's: GQA K/V pools are
+[nb, bs, hkv * d] (lane-dense, the layout the paged-attention kernel
+reads), their quantized scale tiles [nb, bs, hkv], MLA latent pools
+[nb, bs, C]. Everything here addresses the block and position axes and
+passes the trailing dims through.
+
 Block 0 is the reserved **null block**: it is never allocated, inactive
 slots' tables point at it, and any stray write (a masked-out slot in the
 batched decode step) lands there harmlessly. A slot therefore only ever

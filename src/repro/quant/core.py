@@ -158,9 +158,10 @@ def quantize_lastdim(x: Array, fmt: QuantFormat) -> tuple[Array, Array]:
     """Per-vector symmetric quantization over the trailing axis.
 
     x: [..., D] any float dtype. Returns (q [..., D] fmt.dtype,
-    scales [...] f32). One scale per trailing vector — for a KV pool
-    [nb, bs, H, D] that is one scale per (block, token-row, head), stored
-    alongside the block so it rides the block table exactly like the data.
+    scales [...] f32). One scale per trailing vector — for K/V rows
+    [.., H, D] that is one scale per (token, head), pooled [nb, bs, H]
+    alongside the [nb, bs, H * D] payload block so it rides the block
+    table exactly like the data.
     """
     x = x.astype(jnp.float32)
     amax = jnp.max(jnp.abs(x), axis=-1)

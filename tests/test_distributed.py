@@ -245,3 +245,28 @@ def test_elastic_checkpoint_remesh():
                                           np.ones(8, np.float32))
         print("OK")
     """)
+
+
+@pytest.mark.parametrize("kv_heads,split", [(8, True), (2, False)])
+def test_serve_cache_shardings_split_whole_kv_heads(kv_heads, split):
+    """GQA pools are stored [L, NB, bs, hkv * d]: the model axis splits
+    their feature dim only where the kv heads divide by it, so a rank
+    holds whole heads, with the scale tiles split alike; the block axis
+    goes over data. Shapes only, on an abstract (data 2, model 4) mesh."""
+    from jax.sharding import AbstractMesh
+
+    from repro.configs import get_config, reduced
+    from repro.distributed.sharding import serve_cache_shardings
+    from repro.models import api
+    from repro.models.paged import PagedLayout
+
+    cfg = reduced(get_config("qwen1.5-0.5b")).with_(
+        num_layers=2, num_heads=8, num_kv_heads=kv_heads, head_dim=64,
+        kv_dtype="int8")
+    mesh = AbstractMesh((2, 4), ("data", "model"))
+    struct = api.cache_specs(cfg, 4, PagedLayout(16, 4), num_blocks=16)
+    shardings = serve_cache_shardings(cfg, struct, mesh, 4)
+    specs = {k: v.spec for k, v in shardings[0].items()}
+    feature = "model" if split else None
+    for name in ("kpool", "vpool", "kscale", "vscale"):
+        assert tuple(specs[name]) == (None, "data", None, feature), name

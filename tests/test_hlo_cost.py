@@ -126,8 +126,8 @@ def test_paged_attention_superkernel_blocks_linear():
     from repro.kernels import ops
 
     bs, hkv, hq, d, b = 16, 2, 4, 32, 2
-    kpool = jax.ShapeDtypeStruct((9, bs, hkv, d), jnp.float32)
-    vpool = jax.ShapeDtypeStruct((9, bs, hkv, d), jnp.float32)
+    kpool = jax.ShapeDtypeStruct((9, bs, hkv * d), jnp.float32)
+    vpool = jax.ShapeDtypeStruct((9, bs, hkv * d), jnp.float32)
     q = jax.ShapeDtypeStruct((b, 1, hq, d), jnp.float32)
     lens = jax.ShapeDtypeStruct((b,), jnp.int32)
 

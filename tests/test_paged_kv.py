@@ -64,6 +64,69 @@ def test_pool_roundtrip_bitwise():
     assert np.all(back[:, 37:] == 0)
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
+def test_gqa_pools_stored_lane_dense(kv_dtype):
+    """Every GQA pool leaf is stored [L, nb, bs, hkv * d], the layout the
+    paged-attention kernel reads; quantized scale tiles stay
+    [L, nb, bs, hkv]."""
+    cfg = reduced(get_config("qwen1.5-0.5b")).with_(
+        num_layers=3, num_heads=4, num_kv_heads=2, head_dim=64,
+        kv_dtype=kv_dtype)
+    kv = api.KVCache.build(cfg, max_context=64, block_size=16, max_slots=2)
+    nb, bs = kv.num_blocks, kv.layout.block_size
+    shapes = {str(getattr(p[-1], "key", p[-1])): leaf.shape
+              for p, leaf in jax.tree_util.tree_flatten_with_path(
+                  kv.specs(2))[0]}
+    assert shapes["kpool"] == shapes["vpool"] == (3, nb, bs, 2 * 64)
+    if kv_dtype == "bf16":
+        assert "kscale" not in shapes
+    else:
+        assert shapes["kscale"] == shapes["vscale"] == (3, nb, bs, 2)
+    # the prefill's pools take the same layout as the spec
+    params = common.init_params(api.schema(cfg), jax.random.key(0))
+    _, caches = jax.jit(api.prefill_fn(cfg, kv.layout))(
+        params, {"tokens": jnp.asarray([[3, 1, 4, 1, 5]], jnp.int32)})
+    for p, leaf in jax.tree_util.tree_flatten_with_path(caches)[0]:
+        name = str(getattr(p[-1], "key", p[-1]))
+        if name in paged.POOL_KEYS:
+            assert leaf.shape[0] == 3 and leaf.shape[2:] == shapes[name][2:]
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8", "fp8"])
+def test_lane_dense_pool_roundtrip_bitwise(kv_dtype):
+    """K/V rows written to the lane-dense pools (quantized per (token,
+    head), then flattened) and gathered back through the table come out
+    as the padded rows bitwise: the stored layout moves no bits."""
+    from repro.models import attention
+    from repro.quant import core as qcore
+
+    layout = PagedLayout(8, 5)
+    b, s, hkv, d = 3, 37, 2, 16
+    rows = jax.random.normal(jax.random.key(0), (2, b, s, hkv, d),
+                             jnp.bfloat16)
+    fmt = qcore.get_format(kv_dtype)
+    pools, want = {}, []
+    for name, scale, r in zip(("kpool", "vpool"), ("kscale", "vscale"),
+                              rows):
+        if fmt is None:
+            payload = r
+            want.append(r)
+        else:
+            payload, sc = qcore.quantize_lastdim(r, fmt)
+            pools[scale] = paged.pool_from_rows(sc, layout)
+            want.append(qcore.dequantize_lastdim(payload, sc, jnp.bfloat16))
+        pools[name] = paged.pool_from_rows(attention._flat_heads(payload),
+                                           layout)
+        assert pools[name].shape == (1 + b * 5, 8, hkv * d)
+    table = paged.identity_table(b, layout)
+    back = attention._gather_kv(pools, table, fmt, jnp.bfloat16, hkv)
+    for got, w in zip(back, want):
+        assert got.shape == (b, 40, hkv, d)
+        assert np.array_equal(np.asarray(got[:, :s], np.float32),
+                              np.asarray(w, np.float32))
+        assert np.all(np.asarray(got[:, s:], np.float32) == 0)
+
+
 def test_scatter_token_and_chunk():
     layout = PagedLayout(4, 3)
     pool = jnp.zeros((1 + 2 * 3, 4, 2), jnp.float32)

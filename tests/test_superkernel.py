@@ -21,7 +21,7 @@ import pytest
 
 from repro.configs import get_config, reduced
 from repro.kernels import ops
-from repro.models import api, common, paged
+from repro.models import api, attention, common, paged
 from repro.models.paged import PagedLayout
 from repro.quant import core as qcore
 
@@ -33,18 +33,36 @@ DTYPES = ("bf16", "int8", "fp8")
 # ------------------------------------------------------------ fixtures ----
 
 def _pools(seed, b, s, hkv, d, layout, fmt_name):
-    """Paged K/V pools in the given payload dtype (+ scale pools or None)."""
+    """Paged K/V pools in the given payload dtype (+ scale pools or None),
+    in the stored layout: payloads [nb, bs, hkv * d] (quantized per
+    (token, head) first, then flattened), scales [nb, bs, hkv]."""
     rows_k = jax.random.normal(jax.random.key(seed), (b, s, hkv, d))
     rows_v = jax.random.normal(jax.random.key(seed + 1), (b, s, hkv, d))
     fmt = qcore.get_format(fmt_name)
+    flat = lambda x: x.reshape(b, s, hkv * d)
     if fmt is None:
-        return (paged.pool_from_rows(rows_k.astype(jnp.bfloat16), layout),
-                paged.pool_from_rows(rows_v.astype(jnp.bfloat16), layout),
-                None, None)
-    qk, sk = qcore.quantize_lastdim(rows_k, fmt)
-    qv, sv = qcore.quantize_lastdim(rows_v, fmt)
-    return (paged.pool_from_rows(qk, layout), paged.pool_from_rows(qv, layout),
-            paged.pool_from_rows(sk, layout), paged.pool_from_rows(sv, layout))
+        pools = (paged.pool_from_rows(flat(rows_k.astype(jnp.bfloat16)),
+                                      layout),
+                 paged.pool_from_rows(flat(rows_v.astype(jnp.bfloat16)),
+                                      layout),
+                 None, None)
+    else:
+        qk, sk = qcore.quantize_lastdim(rows_k, fmt)
+        qv, sv = qcore.quantize_lastdim(rows_v, fmt)
+        pools = (paged.pool_from_rows(flat(qk), layout),
+                 paged.pool_from_rows(flat(qv), layout),
+                 paged.pool_from_rows(sk, layout),
+                 paged.pool_from_rows(sv, layout))
+    # the layout the cache stores, handed to the kernel as it is
+    spec = attention.gqa_cache_spec(
+        b, layout, attention.AttnConfig(num_heads=hkv, num_kv_heads=hkv,
+                                        head_dim=d, kv_dtype=fmt_name),
+        num_blocks=pools[0].shape[0])
+    for name, pool in zip(("kpool", "vpool", "kscale", "vscale"), pools):
+        if pool is not None:
+            assert pool.shape == spec[name].shape, (name, pool.shape)
+            assert pool.dtype == spec[name].dtype, (name, pool.dtype)
+    return pools
 
 
 def _permute(pools, table, seed=3):
@@ -66,12 +84,13 @@ def _dequant_first_oracle(q, kpool, vpool, kscale, vscale, table, lens, offs):
     Deliberately the opposite formulation from the kernel (which folds
     scales post-dot into the compensated streams), so agreement here is
     evidence the refactor changed only the evaluation order."""
-    k = qcore.cast_f32(paged.gather_blocks(kpool, table))
-    v = qcore.cast_f32(paged.gather_blocks(vpool, table))
+    b, w, hq, d = q.shape
+    heads = lambda x: x.reshape(x.shape[:2] + (-1, d))    # gathered rows
+    k = heads(qcore.cast_f32(paged.gather_blocks(kpool, table)))
+    v = heads(qcore.cast_f32(paged.gather_blocks(vpool, table)))
     if kscale is not None:
         k = k * paged.gather_blocks(kscale, table)[..., None]
         v = v * paged.gather_blocks(vscale, table)[..., None]
-    b, w, hq, d = q.shape
     g = hq // k.shape[2]
     qf = q.astype(jnp.float32).reshape(b, w, -1, g, d)
     s = jnp.einsum("bwhgd,bshd->bwhgs", qf, k) * (d ** -0.5)
@@ -202,8 +221,6 @@ def test_gqa_decode_kernel_dispatch(monkeypatch, kv_dtype):
     """The TPU dispatch branch of gqa_decode (superkernel, interpret mode
     off-TPU) agrees with the pure-JAX gather branch through a full model
     decode step, for every pool dtype."""
-    from repro.models import attention
-
     cfg = reduced(get_config("qwen1.5-0.5b")).with_(num_layers=2,
                                                     kv_dtype=kv_dtype)
     params = common.init_params(api.schema(cfg), jax.random.key(0))
@@ -230,8 +247,6 @@ def test_kernel_verify_bitwise_equals_sequential_decode(monkeypatch,
     bitwise identical to k+1 sequential decode steps, for all three pool
     dtypes — the end-to-end form of the width-invariance contract that
     makes speculative acceptance exact."""
-    from repro.models import attention
-
     monkeypatch.setattr(attention, "paged_kernel_enabled", lambda: True)
     cfg = reduced(get_config("qwen1.5-0.5b")).with_(num_layers=2,
                                                     kv_dtype=kv_dtype)

@@ -7,8 +7,10 @@ described (not attached) v5e chip at real widths: the paged-attention
 superkernel at qwen1.5-0.5b's (GQA; bf16, int8 and fp8 pools; decode and
 verify widths) and deepseek-v2's (MLA latents), the reduction engine's
 row variant at the logit-stats shape and its flat variant at the paper's
-dot sweep, and the whole qwen1.5-0.5b decode step with the kernel
-dispatch switched on. A compile proves lowering and fit; it runs nothing.
+dot sweep, and the whole qwen1.5-0.5b decode step (kernel dispatch
+switched on) and prefill-chunk program, neither of which may copy a KV
+pool into another layout. A compile proves lowering and fit; it runs
+nothing.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the worker given this file is
@@ -20,6 +22,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_config
+from repro.ecm import hlo_cost
 from repro.kernels import engine, ops
 from repro.kernels import paged_attention as pa
 from repro.models import api, attention, common
@@ -64,6 +67,14 @@ def _kernel_calls(compiled, name: str) -> int:
                and f"/{name}/" in line)
 
 
+def _pool_relayouts(compiled, caches) -> list:
+    """Copies of a pool layer or a stacked pool into a non-default
+    layout: none, so no launch re-lays a stored pool out."""
+    nb = caches[0]["kpool"].shape[1]
+    return [c for c in hlo_cost.pool_copies(compiled.as_text(), nb)
+            if not c.default]
+
+
 # ---------------------------------------------------------- superkernel ----
 
 @pytest.mark.parametrize("kv_dtype,width", [
@@ -80,8 +91,8 @@ def test_superkernel_gqa_compiles(one_chip, kv_dtype, width):
     pool_dtype = jnp.bfloat16 if fmt is None else fmt.storage
     s = lambda shape, dt: _spec(one_chip, shape, dt)
     args = [s((SLOTS, width, cfg.num_heads, d), jnp.bfloat16),
-            s((nb, BLOCK, hkv, d), pool_dtype),
-            s((nb, BLOCK, hkv, d), pool_dtype),
+            s((nb, BLOCK, hkv * d), pool_dtype),
+            s((nb, BLOCK, hkv * d), pool_dtype),
             s((SLOTS, layout.max_blocks), jnp.int32),
             s((SLOTS,), jnp.int32), s((SLOTS,), jnp.int32)]
     if fmt is None:
@@ -167,6 +178,28 @@ def test_decode_step_compiles_with_kernels(one_chip, monkeypatch):
     compiled = _compile(step, params, tokens, caches)
     assert _kernel_calls(compiled, "paged_attention") >= 1
     assert _kernel_calls(compiled, "fused_reduce_rows") == 2
+    assert _pool_relayouts(compiled, caches) == []
+    mem = compiled.memory_analysis()
+    need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert need < V5E_HBM_BYTES, need
+
+
+def test_prefill_chunk_compiles_without_pool_relayout(one_chip):
+    """The whole qwen1.5-0.5b prefill-chunk program (32 tokens of one
+    slot into the 8-slot pools): it fits one chip, and no copy re-lays a
+    pool layer or the stacked pools out."""
+    cfg = get_config("qwen1.5-0.5b")
+    layout = PagedLayout.for_context(MAX_CONTEXT, BLOCK)
+    place = lambda t: jax.tree.map(
+        lambda x: _spec(one_chip, x.shape, x.dtype), t)
+    params = place(common.abstract_params(api.schema(cfg)))
+    caches = place(api.cache_specs(cfg, SLOTS, layout))
+    scalar = _spec(one_chip, (), jnp.int32)
+    compiled = _compile(api.prefill_chunk_fn(cfg), params,
+                        _spec(one_chip, (1, 32), jnp.int32), caches,
+                        scalar, scalar)
+    assert _pool_relayouts(compiled, caches) == []
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
