@@ -1,9 +1,12 @@
-"""The comparison that decides ``correct`` for a served dense model.
+"""The comparison that decides ``correct`` for a served model.
 
 Once the window has closed and the engine is freed, a sample of the
 requests the window finished, drawn from the seed and holding the longest
-of them, is run through the plain float32 reference, teacher-forced on
-each prompt with its served tokens. Two numbers are compared:
+of them, is run through the plain float32 reference of the model family
+that the configuration names (``bench/families/<family>.py``: its
+``hidden`` and ``head_stats``, on its weights from the seed),
+teacher-forced on each prompt with its served tokens. Two numbers are
+compared:
 
 ``logit_gap``    the widest gap by which a served token's reference
                  logit lies below the reference's best at that position
@@ -19,10 +22,11 @@ tokens, the token that the lower precision puts first, and its logprob.
 
 ``fp8``        every matmul's inputs rounded to float8 e4m3's 3 mantissa
                bits, one precision below the bfloat16 the configurations
-               state for them (``dense.ROUNDING["fp8"]``);
+               state for them (the family's ``hidden`` and
+               ``head_stats`` with ``rounding="fp8"``);
 ``bf16_stats`` the f32 logits and the compensated f32 logit statistics
                that the configurations state, computed in bfloat16
-               (``dense.head_stats(..., stats="bf16")``).
+               (the family's ``head_stats(..., stats="bf16")``).
 """
 
 from __future__ import annotations
@@ -31,8 +35,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
-from bench import loadgen, weights
-from bench.reference import dense
+from bench import loadgen, spec
 
 ROW_PAD = 128           # served positions are scored in blocks of this size
 SEQ_PAD = 128           # sequences are padded to a multiple of this size
@@ -71,8 +74,9 @@ def compare(config: dict, seed: int, reqs: list[Served], max_tokens: int,
             controls: tuple = ()) -> dict:
     """The compared numbers over ``reqs``, and under ``"controls"`` those
     of each control named."""
-    m = weights.Dims.from_config(config)
-    params = weights.make_params(m, seed)
+    family = spec.load_family(config)
+    m = family.dims(config)
+    params = family.make_params(m, seed)
     seq_len = loadgen.ceil_to(max_tokens, SEQ_PAD)
     gaps, errs = [], []
     c_gaps = {c: [] for c in controls}
@@ -86,8 +90,8 @@ def compare(config: dict, seed: int, reqs: list[Served], max_tokens: int,
         n_tokens += n
         rows = len(r.prompt) - 1 + np.arange(n)
         ids = np.asarray(r.output, np.int32)
-        h = dense.hidden(params, seq, m)
-        hc = {c: dense.hidden(params, seq, m, CONTROLS[c]["rounding"])
+        h = family.hidden(params, seq, m)
+        hc = {c: family.hidden(params, seq, m, CONTROLS[c]["rounding"])
               if "rounding" in CONTROLS[c] else h for c in controls}
         for b in range(0, n, ROW_PAD):
             sl = slice(b, min(b + ROW_PAD, n))
@@ -96,16 +100,16 @@ def compare(config: dict, seed: int, reqs: list[Served], max_tokens: int,
             rr[:k] = rows[sl]
             ii = np.zeros(ROW_PAD, np.int32)
             ii[:k] = ids[sl]
-            ref = dense.head_stats(params, h, rr, ii, m)
+            ref = family.head_stats(params, h, rr, ii, m)
             gaps.append((ref["max"] - ref["at"])[:k])
             lp_ref = (ref["at"] - ref["lse"])[:k]
             errs.append(np.abs(np.asarray(r.logprobs[sl.start:sl.stop])
                                - lp_ref))
             for name in controls:
-                c = dense.head_stats(params, hc[name], rr, ii, m,
-                                     **CONTROLS[name])
+                c = family.head_stats(params, hc[name], rr, ii, m,
+                                      **CONTROLS[name])
                 a = c["argmax"].astype(np.int32)
-                at = dense.head_stats(params, h, rr, a, m)["at"]
+                at = family.head_stats(params, h, rr, a, m)["at"]
                 c_gaps[name].append((ref["max"] - at)[:k])
                 c_errs[name].append(np.abs((c["max"] - c["lse"])
                                            - (at - ref["lse"]))[:k])

@@ -7,7 +7,9 @@ cache, requires a TPU with as many chips as the cell asks for (it never
 falls back to the CPU), makes the cell's weights on the device from the
 seed, warms up the cell's own shapes, serves the cell's traffic for
 ``--seconds`` of measured window and checks what the window served
-against the plain reference. The last line of standard output is one
+against the plain reference. Weights, reference and work counts are
+those of the model family that the cell's configuration names
+(``bench/families/<family>.py``). The last line of standard output is one
 JSON object: ``correct``, ``attempted``, ``failed``, ``metrics``,
 ``device`` (and with ``--trace 1`` ``breakdown``), then ``compared``: each
 number compared beside its limit, which are also the last lines of
@@ -34,7 +36,7 @@ for p in (ROOT / "src", ROOT):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from bench import check, loadgen, spec, weights  # noqa: E402
+from bench import check, loadgen, spec  # noqa: E402
 from bench import trace as tracemod  # noqa: E402
 from bench.drive import Driver  # noqa: E402
 
@@ -47,18 +49,15 @@ class NoAccelerator(RuntimeError):
 
 
 def program_config(config: dict):
-    """The program's ModelConfig for ``config``, checked against it."""
+    """The program's ModelConfig for ``config``, checked against what its
+    family wants of it, and the family's sizes."""
     from repro.configs import get_config
     prog = config["program"]
     cfg = get_config(prog["arch"]).with_(
         **prog.get("overrides", {}), kv_dtype=config["engine"]["kv_dtype"])
-    m = weights.Dims.from_config(config)
-    want = {"d_model": m.d, "d_ff": m.ff, "num_heads": m.heads,
-            "num_kv_heads": m.kv_heads, "num_layers": m.layers,
-            "vocab_size": m.vocab, "rope_theta": m.rope_theta,
-            "tie_embeddings": m.tied, "head_dim": m.head_dim,
-            "norm": m.norm, "qkv_bias": m.qkv_bias, "act": "swiglu",
-            "rotary_fraction": m.rotary_dim / m.head_dim, "family": "dense"}
+    family = spec.load_family(config)
+    m = family.dims(config)
+    want = family.program_want(m)
     bad = {k: (getattr(cfg, k), v) for k, v in want.items()
            if getattr(cfg, k) != v}
     if bad:
@@ -77,7 +76,7 @@ def check_layout(cfg, params) -> None:
     if jax.tree.structure(want) != jax.tree.structure(got) or any(
             a.shape != b.shape for a, b in zip(jax.tree.leaves(want),
                                                jax.tree.leaves(got))):
-        raise ValueError("bench.weights layout differs from the program's "
+        raise ValueError("the family's weights differ from the program's "
                          "parameter schema")
 
 
@@ -154,12 +153,13 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     from repro.serving.engine import DecodeEngine, Request
 
     config = cell.config
+    family = spec.load_family(config)
     cfg, dims = program_config(config)
     geo = config["engine"]
     stamp = lambda what: log(f"bench: {what} at "
                              f"{time.perf_counter() - t_process:.2f} s")
     stamp("devices ready")
-    params = weights.make_params(dims, seed)
+    params = family.make_params(dims, seed)
     jax.block_until_ready(params)
     stamp("weights made")
     check_layout(cfg, params)
@@ -221,7 +221,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
 
     # what a metric reader may read (bench/metrics/<name>.py)
     view = types.SimpleNamespace(
-        run=rec, dims=dims, engine=geo, setup_s=setup_s, events=events,
+        run=rec, dims=dims, family=family, engine=geo, setup_s=setup_s,
+        events=events,
         peak=peak_entry(devices[0].device_kind)
         if devices[0].platform == "tpu" else None)
     wanted = cell.per_layer if trace else cell.end_to_end

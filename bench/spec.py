@@ -1,21 +1,26 @@
-"""Find a cell's configuration, traffic mix and metrics by name.
+"""Find a cell's configuration, model family, traffic mix and metrics by
+name.
 
-Everything that belongs to one configuration, one mix or one per-layer
-metric sits in a file of its own, so a later cell adds files and edits
-none: ``configs/<config>.json``, ``traffic/<mix>.json`` (which may
-``extend`` another mix and override some of its keys) and
-``metrics/<metric>.py``.
+Everything that belongs to one configuration, one model family, one mix
+or one per-layer metric sits in a file of its own, so a later cell adds
+files and edits none: ``configs/<config>.json``, which names its family
+under ``architecture.family``; ``families/<family>.py``, the family's
+weights, reference and work counts (``bench.families``);
+``traffic/<mix>.json`` (which may ``extend`` another mix and override
+some of its keys) and ``metrics/<metric>.py``.
 """
 
 from __future__ import annotations
 
 import importlib.util
 import json
+import sys
 from dataclasses import dataclass
 from pathlib import Path
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
+FAMILIES_DIR = BENCH_DIR / "families"
 
 
 def load_json(path: Path) -> dict:
@@ -68,6 +73,7 @@ def load_cell(name: str, bench_json: Path = ROOT / "BENCHMARK.json") -> Cell:
     w = cells[name]
     conf = next(c for c in bench["configs"] if c["name"] == w["config"])
     config = load_json(ROOT / conf["file"])
+    family_path(config)
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
     names = {m["name"] for m in e2e}
@@ -85,3 +91,38 @@ def load_reader(metric: str, metrics_dir: Path = BENCH_DIR / "metrics"):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+def family_path(config: dict, families_dir: Path = FAMILIES_DIR) -> Path:
+    """The module of the family that ``config`` names; an error that names
+    the file looked for where it names none or an unknown one."""
+    family = config.get("architecture", {}).get("family")
+    if family is None:
+        raise ValueError(f"configuration {config.get('name')!r} names no "
+                         f"architecture.family: the module "
+                         f"{families_dir}/<family>.py it would load")
+    path = families_dir / f"{family}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {config.get('name')!r} "
+                                f"names family {family!r}, and there is no "
+                                f"{path}")
+    return path
+
+
+def load_family(config: dict, families_dir: Path = FAMILIES_DIR):
+    """The module ``families/<family>.py`` of the family that ``config``
+    names, imported once per file."""
+    path = family_path(config, families_dir)
+    name = f"bench.families.{path.stem}"
+    mod = sys.modules.get(name)
+    if mod is not None and Path(mod.__file__).resolve() == path.resolve():
+        return mod
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    try:
+        spec.loader.exec_module(mod)
+    except BaseException:
+        del sys.modules[name]
+        raise
+    return mod

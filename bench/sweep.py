@@ -24,7 +24,7 @@ for p in (ROOT / "src", ROOT):
     if str(p) not in sys.path:
         sys.path.insert(0, str(p))
 
-from bench import loadgen, spec, stats, views, weights  # noqa: E402
+from bench import loadgen, spec, stats, views  # noqa: E402
 from bench import run as runmod  # noqa: E402
 from bench.drive import Driver  # noqa: E402
 
@@ -48,7 +48,7 @@ def main(argv=None) -> int:
     from repro.serving.engine import DecodeEngine, Request
     cfg, dims = runmod.program_config(cell.config)
     geo = cell.config["engine"]
-    params = weights.make_params(dims, args.seed)
+    params = spec.load_family(cell.config).make_params(dims, args.seed)
     engine = DecodeEngine(cfg, params, max_slots=geo["max_slots"],
                           max_context=geo["max_context"],
                           block_size=geo["block_size"],

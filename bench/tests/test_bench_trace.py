@@ -13,7 +13,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
-from bench import spec, views, weights  # noqa: E402
+from bench import spec, views  # noqa: E402
 from bench import trace as tr  # noqa: E402
 from bench.drive import Record, StepLog  # noqa: E402
 
@@ -35,9 +35,10 @@ def view(recorded):
                  trace_span=(steps[0].t0, steps[-1].t1))
     config = spec.load_json(spec.BENCH_DIR / "configs" / "qwen1.5-0.5b.json")
     peaks = spec.load_json(spec.BENCH_DIR / "peaks.json")
+    family = spec.load_family(config)
     return types.SimpleNamespace(
         run=run, events=recorded["events"], engine=recorded["engine"],
-        dims=weights.Dims.from_config(config), peak=peaks["TPU v5 lite"])
+        dims=family.dims(config), family=family, peak=peaks["TPU v5 lite"])
 
 
 def test_window_and_busy_time(recorded):
@@ -70,6 +71,16 @@ def test_device_readers_find_their_numbers(view, metric):
     assert value is not None and value > 0
     if metric.endswith(("_roofline", "_mfu", "_share")):
         assert value <= 100
+
+
+# What these readers gave on this trace before they took their work counts
+# from the configuration's family: the same sums in the same order, so the
+# same bits.
+@pytest.mark.parametrize("metric, value", [
+    ("decode_mfu", 0.0478224745673934),
+    ("paged_attention_roofline", 0.9782609342347808)])
+def test_family_readers_keep_their_values(view, metric, value):
+    assert spec.load_reader(metric)(view) == value
 
 
 def test_breakdown_names_ops_and_host_spans(recorded):
