@@ -205,7 +205,9 @@ def pool_layouts(label: str, text: str, caches) -> None:
 
 def decode_args(engine):
     import jax.numpy as jnp
-    return (engine.params, jnp.asarray(engine._next_tokens), engine.caches)
+    live = jnp.ones((SLOTS,), bool)
+    return (engine.params, (jnp.asarray(engine._next_tokens), live),
+            engine.caches)
 
 
 def prefill_chunk_args(engine):

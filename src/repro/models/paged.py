@@ -121,9 +121,9 @@ def scatter_token(pool: Array, table: Array, lens: Array, vals: Array
     """Write one token per sequence at its current length.
 
     pool [nb, bs, ...]; table [B, mb]; lens [B]; vals [B, ...]. Out-of-range
-    positions (a retired slot whose length keeps drifting in the batched
-    step) clip into the table row, whose stale entries are the null block —
-    the write is absorbed there.
+    positions (an idle slot whose length a batched step lets drift, as the
+    draft model's does between drafts) clip into the table row, whose stale
+    entries are the null block — the write is absorbed there.
     """
     bs, mb = pool.shape[1], table.shape[1]
     blk_idx = jnp.clip(lens // bs, 0, mb - 1)
@@ -183,13 +183,14 @@ def keep_slots(old, new, keep_mask: Array):
     """Merge two batched LM cache trees after a full-batch step: slots
     flagged in ``keep_mask`` ([B] bool) keep their OLD per-slot state.
 
-    The batched decode step updates every slot — including ones that are
-    mid-chunked-prefill. Attention slots tolerate that (the stray token
-    write is positional and the next chunk overwrites it), but recurrent
-    per-slot state (SSM state/conv window, ``len``) would be polluted for
-    good. Shared pool leaves pass through from ``new`` (their stray writes
-    land inside the protected slot's own blocks at positions the next
-    chunk rewrites, or in the null block).
+    The batched decode step updates every slot — including free ones and
+    ones that are mid-chunked-prefill. Attention slots tolerate the stray
+    token write (it is positional, and the next chunk overwrites it or the
+    null block absorbs it), but recurrent per-slot state (SSM state/conv
+    window, ``len``) would be polluted for good, and a free slot's ``len``
+    would grow every step. Shared pool leaves pass through from ``new``
+    (their stray writes land inside the protected slot's own blocks at
+    positions the next chunk rewrites, or in the null block).
     """
     from jax.tree_util import tree_map_with_path
 

@@ -552,6 +552,41 @@ def _sample_rows(rows: jax.Array, temperatures: jax.Array, keys: jax.Array,
         rows, temperatures, keys)
 
 
+def fused_decode_fn(cfg: ModelConfig):
+    """The engine's one launch per decode step:
+    (params, (tokens [B, 1] int32, live [B] bool), caches)
+        -> (rows [B, V], packed [1 + 6, B] f32, caches).
+
+    ``live`` marks the decoding rows. Only they advance: every other row
+    (free, retired, mid-prefill) keeps its ``len`` and recurrent state,
+    so the paged-attention kernel walks one block for an idle row rather
+    than a length that grows by one every step, and a prefilling row
+    stays at its chunk progress. ``live`` rides beside the tokens, so
+    code that wraps the launch keeps its three-operand call.
+    """
+    decode_raw = api.decode_fn(cfg)
+
+    def _decode_fused(params, feed, caches):
+        # Model step + held rows + greedy choice + the fused _logit_stats
+        # metrics, packed [1 + 6, B] f32 so a single host transfer
+        # carries everything the scheduler reads. Separate jit calls for
+        # choice/stats plus one sync per stat array cost ~25% of a CPU
+        # decode step. Token ids ride the f32 packing exactly (vocab <<
+        # 2^24). ``rows`` comes back device-side, untransferred, for the
+        # sampling / fault-injection override paths.
+        tokens, live = feed
+        logits, new_caches = decode_raw(params, tokens, caches)
+        new_caches = paged.keep_slots(caches, new_caches, ~live)
+        rows = logits.reshape(logits.shape[0], -1)
+        toks = jnp.argmax(rows, axis=-1).astype(jnp.int32)
+        stats = _logit_stats(rows, toks)
+        packed = jnp.stack([toks.astype(jnp.float32)]
+                           + [stats[k] for k in _STAT_KEYS])
+        return rows, packed, new_caches
+
+    return _decode_fused
+
+
 class DecodeEngine:
     """Paged continuous-batching engine over a fixed slot pool.
 
@@ -656,28 +691,8 @@ class DecodeEngine:
                 help="engine steps from submit to slot admission")
 
         self._prefill_chunk = jax.jit(api.prefill_chunk_fn(cfg))
-        decode_raw = api.decode_fn(cfg)
-
-        def _decode_fused(params, tokens, caches):
-            # One launch per decode step: model step + greedy choice +
-            # the fused _logit_stats metrics, packed [1 + 6, B] f32 so a
-            # single host transfer carries everything the scheduler
-            # reads. Separate jit calls for choice/stats plus one sync
-            # per stat array cost ~25% of a CPU decode step. Token ids
-            # ride the f32 packing exactly (vocab << 2^24). ``rows``
-            # comes back device-side, untransferred, for the sampling /
-            # fault-injection override paths.
-            logits, new_caches = decode_raw(params, tokens, caches)
-            rows = logits.reshape(logits.shape[0], -1)
-            toks = jnp.argmax(rows, axis=-1).astype(jnp.int32)
-            stats = _logit_stats(rows, toks)
-            packed = jnp.stack([toks.astype(jnp.float32)]
-                               + [stats[k] for k in _STAT_KEYS])
-            return rows, packed, new_caches
-
-        self._decode = jax.jit(_decode_fused)
+        self._decode = jax.jit(fused_decode_fn(cfg))
         self._reset_slot = jax.jit(paged.reset_slot)
-        self._keep_slots = jax.jit(paged.keep_slots)
         self._set_lens = jax.jit(paged.set_lens)
         self._copy_block = jax.jit(paged.copy_block)
 
@@ -686,6 +701,10 @@ class DecodeEngine:
         # eager device scatter per token costs more than the whole
         # decode launch on CPU — upload once per step instead
         self._next_tokens = np.zeros((max_slots, 1), np.int32)
+        # the decode launch's ``live`` row mask and the decoding slots it
+        # was built from: uploaded again only when that set changes
+        self._live = None
+        self._live_slots = None
         # The all-NULL table row every slot teardown points back at.
         # Built ONCE: retire/terminate/preempt/quarantine sit on the hot
         # path, and rebuilding this constant per retirement costs a fresh
@@ -734,7 +753,7 @@ class DecodeEngine:
                          "preempted": 0, "preempted_blocks": 0,
                          "restored_blocks": 0, "guard_trips": 0,
                          "cancelled": 0, "expired": 0, "alloc_faults": 0,
-                         "stalled_requests": 0}
+                         "stalled_requests": 0, "held_rows": 0}
 
         # Session spill tier: evicted trie blocks snapshot to host and
         # can promote back into free blocks. Armed last — the snapshot
@@ -1269,7 +1288,7 @@ class DecodeEngine:
         "stalled_requests": "requests", "spec_steps": "steps",
         "spec_slot_steps": "walks", "spec_drafted": "tokens",
         "spec_accepted": "tokens", "spec_emitted": "tokens",
-        "proposer_stalls": "stalls",
+        "proposer_stalls": "stalls", "held_rows": "rows",
     }
 
     def metrics_registry(self) -> obs.MetricsRegistry:
@@ -1395,21 +1414,10 @@ class DecodeEngine:
                                    batch=len(self.scheduler.decoding))
         prof = self.obs.profile
         t0 = time.perf_counter() if prof is not None else 0.0
-        prefilling = [r.slot for r in self.scheduler.prefilling]
-        before = self.caches
         tok_in = jnp.asarray(self._next_tokens)
+        feed = (tok_in, self._live_mask())
         rows, packed_dev, self.caches = self._decode(
-            self.params, tok_in, self.caches)
-        if prefilling:
-            # The full-batch decode also "stepped" slots that are mid-
-            # chunked-prefill. Their pool writes are harmless (overwritten
-            # by the next chunk), but recurrent per-slot state (SSM
-            # state/conv, len) must be restored or the continuation
-            # diverges from solo generation.
-            mask = np.zeros((self.max_slots,), bool)
-            mask[prefilling] = True
-            self.caches = self._keep_slots(before, self.caches,
-                                           jnp.asarray(mask))
+            self.params, feed, self.caches)
         injected = (self.injector is not None
                     and self.injector.fire("logit_nan", self._step_count))
         if injected:
@@ -1503,11 +1511,22 @@ class DecodeEngine:
             # frame shapes never change (static_shapes)
             prof.record_call(
                 "decode_step", self._decode,
-                (self.params, tok_in, self.caches),
+                (self.params, feed, self.caches),
                 wall_s=time.perf_counter() - t0,
                 host_bytes=tok_in.nbytes + tokens.nbytes
                 + sum(int(v.nbytes) for v in self.last_logit_stats.values()),
                 static_shapes=True)
+
+    def _live_mask(self) -> jax.Array:
+        """[max_slots] bool, true for the decoding slots: the rows the
+        decode launch advances. The device copy is rebuilt only when the
+        decoding set changed since the last step."""
+        slots = frozenset(self.scheduler.decoding)
+        if slots != self._live_slots:
+            mask = np.zeros((self.max_slots,), bool)
+            mask[list(slots)] = True
+            self._live, self._live_slots = jnp.asarray(mask), slots
+        return self._live
 
     def _finished(self, req: Request, tok: int) -> bool:
         return (len(req.output) >= req.max_new_tokens
@@ -1539,6 +1558,9 @@ class DecodeEngine:
                                               * self.layout.max_context
                                               * self._token_bytes)
         self.kv_stats["decode_steps"] += 1
+        # rows the decode launch held at their length, not advanced
+        self.kv_stats["held_rows"] += (self.max_slots
+                                       - len(self.scheduler.decoding))
 
     def _account_prefill(self, cached: int, *, first: bool) -> None:
         bs = self.layout.block_size

@@ -313,3 +313,87 @@ def test_kv_traffic_accounting(setup):
     # the typed metrics snapshot subsumes kv_stats value-for-value
     snap = engine.metrics_snapshot()
     assert all(snap[k] == v for k, v in st.items())
+
+
+def _slot_lens(caches) -> np.ndarray:
+    """Every slot's cached length ([B]), read from the ``len`` leaves of
+    a batched cache tree (one row per layer, all equal)."""
+    rows = [np.asarray(leaf).reshape(-1, np.shape(leaf)[-1])
+            for path, leaf in jax.tree_util.tree_leaves_with_path(caches)
+            if str(getattr(path[-1], "key", path[-1])) == "len"]
+    stacked = np.concatenate(rows)
+    assert (stacked == stacked[0]).all(), stacked
+    return stacked[0]
+
+
+def test_decode_launch_holds_non_decoding_rows(setup):
+    """Inside the fused decode launch only decoding rows advance: a slot
+    never admitted, a retired slot and a slot mid-prefill each leave the
+    launch at the length it came in with, while every decoding row moves
+    by one. Outputs equal each request's solo run; ``held_rows`` counts
+    the rows held, summed over decode launches."""
+    cfg, params = setup
+    slots = 8
+    engine = _engine(cfg, params, max_slots=slots, prefill_chunk=4)
+    launches = []
+    decode = engine._decode
+
+    def recorded(params, feed, caches):
+        decoding = frozenset(engine.scheduler.decoding)
+        prefilling = {r.slot for r in engine.scheduler.prefilling}
+        before = _slot_lens(caches)
+        out = decode(params, feed, caches)
+        launches.append((decoding, prefilling, feed[1], before,
+                         _slot_lens(out[2])))
+        return out
+
+    engine._decode = recorded
+    reqs = [Request(rid=0, prompt=[1, 2, 3], max_new_tokens=16),
+            Request(rid=1, prompt=[4, 5], max_new_tokens=16),
+            Request(rid=2, prompt=[6, 7], max_new_tokens=2)]
+    for r in reqs:
+        engine.submit(r)
+    while not reqs[2].done:
+        engine.step()
+    long_req = Request(rid=3, prompt=list(range(5, 25)), max_new_tokens=3)
+    engine.submit(long_req)            # 5 chunks of 4: mid-prefill a while
+    reqs.append(long_req)
+    engine.run_until_done()
+    assert all(r.done for r in reqs)
+
+    seen = {"decoding": 0, "prefilling": 0, "retired": 0, "free": 0}
+    ever_live = set()
+    for decoding, prefilling, live, before, after in launches:
+        assert set(np.flatnonzero(np.asarray(live))) == set(decoding)
+        for s in range(slots):
+            if s in decoding:
+                assert after[s] == before[s] + 1, (s, before, after)
+                seen["decoding"] += 1
+                continue
+            assert after[s] == before[s], (s, before, after)
+            if s in prefilling:          # admitted, or chunks in
+                seen["prefilling"] += int(before[s] > 0)
+            else:
+                assert before[s] == 0, (s, before)
+                seen["retired" if s in ever_live else "free"] += 1
+        ever_live |= decoding
+    # every state a row can be in at a decode launch was exercised
+    assert all(seen.values()), seen
+    # a steady decoding set reuses its device mask: no upload a step
+    for prev, cur in zip(launches, launches[1:]):
+        if prev[0] == cur[0]:
+            assert cur[2] is prev[2]
+
+    for r in reqs:                     # each equals its solo run
+        solo_engine = _engine(cfg, params, max_slots=slots, prefill_chunk=4)
+        solo = Request(rid=r.rid, prompt=list(r.prompt),
+                       max_new_tokens=r.max_new_tokens)
+        solo_engine.submit(solo)
+        solo_engine.run_until_done()
+        assert r.output == solo.output
+        assert r.logprobs == solo.logprobs
+
+    held = sum(slots - len(d) for d, *_ in launches)
+    assert engine.kv_stats["held_rows"] == held > 0
+    metric = engine.metrics_registry()["held_rows"]
+    assert metric.unit == "rows" and metric.value == held

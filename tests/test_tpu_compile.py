@@ -151,11 +151,12 @@ def test_fused_reduce_flat_dot_compiles(one_chip, unroll):
 # ------------------------------------------------------------ decode step --
 
 def test_decode_step_compiles_with_kernels(one_chip, monkeypatch):
-    """The whole qwen1.5-0.5b decode step (24 layers, vocab 151936) plus
-    the engine's logit-stats pass, with the TPU dispatch switched on: the
-    superkernel and the reduction engine lower inside it, and the program
-    fits one chip's HBM."""
-    from repro.serving.engine import _logit_stats
+    """The engine's whole qwen1.5-0.5b decode launch (24 layers, vocab
+    151936, the logit-stats pass and the merge that holds non-decoding
+    rows), with the TPU dispatch switched on: the superkernel and the
+    reduction engine lower inside it, no pool is re-laid out, and the
+    program fits one chip's HBM."""
+    from repro.serving.engine import fused_decode_fn
 
     monkeypatch.setattr(attention, "paged_kernel_enabled", lambda: True)
     monkeypatch.setattr(ops, "_auto_interpret",
@@ -166,16 +167,9 @@ def test_decode_step_compiles_with_kernels(one_chip, monkeypatch):
         lambda x: _spec(one_chip, x.shape, x.dtype), t)
     params = place(common.abstract_params(api.schema(cfg)))
     caches = place(api.cache_specs(cfg, SLOTS, layout))
-    tokens = _spec(one_chip, (SLOTS, 1), jnp.int32)
-    decode = api.decode_fn(cfg)
-
-    def step(params, tokens, caches):
-        logits, caches = decode(params, tokens, caches)
-        rows = logits.reshape(SLOTS, -1)
-        stats = _logit_stats(rows, jnp.argmax(rows, -1).astype(jnp.int32))
-        return stats, caches
-
-    compiled = _compile(step, params, tokens, caches)
+    feed = (_spec(one_chip, (SLOTS, 1), jnp.int32),
+            _spec(one_chip, (SLOTS,), jnp.bool_))
+    compiled = _compile(fused_decode_fn(cfg), params, feed, caches)
     assert _kernel_calls(compiled, "paged_attention") >= 1
     assert _kernel_calls(compiled, "fused_reduce_rows") == 2
     assert _pool_relayouts(compiled, caches) == []
